@@ -5,9 +5,11 @@ for the big sweep programs it dwarfs the first measured steady-state call
 (the N=1e6 edge-sharded program spends minutes in XLA before the first
 step runs). jax ships an on-disk executable cache
 (``jax_compilation_cache_dir``); :func:`enable` turns it on for the whole
-harness (``benchmarks/run.py --compile-cache DIR``) and the CI bench lane
-persists that directory across workflow runs, so re-benchmarking an
-unchanged program costs a deserialization, not a compile.
+harness (``benchmarks/run.py``) and for ``chip_smoke.py``, rooted at
+``JAX_COMPILATION_CACHE_DIR`` when that is set and at ``<checkout>/.jax-cache``
+otherwise. The CI bench lane persists that directory across workflow
+runs, so re-benchmarking an unchanged program costs a deserialization,
+not a compile.
 
 :func:`rows` pins the claim with two rows over the same lowered program:
 
@@ -34,28 +36,32 @@ from repro.core.graphs import random_strongly_connected_edge_list
 from repro.core.pushsum import run_pushsum_sparse
 
 
-def enable(cache_dir: str) -> None:
-    """Turn on jax's persistent compilation cache rooted at ``cache_dir``.
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFAULT_DIR = os.path.join(REPO, ".jax-cache")
 
+
+def enable() -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already roots the cache
+    there and nothing is set here. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax-cache`` — the path is part of the cache's key, so a
+    directory that moved (a temp name, a pid, a time) would never hit.
     The min-compile-time / min-entry-size floors are dropped to zero so
     the CI smoke programs (which compile in well under a second) are
-    cached too — the lane's whole point. Flags that this jax build lacks
-    are skipped silently rather than gating the harness on a version.
+    cached too. Call before the first compile.
     """
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    for flag, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(flag, val)
-        except AttributeError:
-            pass
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 def _cache_dir() -> str | None:
-    return getattr(jax.config, "jax_compilation_cache_dir", None)
+    return jax.config.jax_compilation_cache_dir
 
 
 def _cache_entries(cache_dir: str | None) -> int:
@@ -87,9 +93,8 @@ def rows(smoke: bool = False):
         cache = "hit"             # served from a pre-populated cache
     else:
         # empty dir and nothing written: the cache is configured but not
-        # taking entries (enable() called after backend init, or the jax
-        # build ignores the min-compile-time floor) — say so instead of
-        # mislabeling it a hit
+        # taking entries (enable() called after the first compile) — say
+        # so instead of mislabeling it a hit
         cache = "uncached"
 
     # drop the in-memory executable so the second compile must go back to

@@ -88,15 +88,32 @@ def _bench_large_sparse(n=1024, d=8, T=64, extra_edge_prob=0.002, seed=0):
 
 def _bytes_per_call(lowered, calls: int) -> float:
     """Per-call 'bytes accessed' from the compiled executable's
-    cost_analysis (a dict on current jax, a [dict] on older builds);
-    NaN when the backend doesn't report it."""
-    try:
-        cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        return float(cost["bytes accessed"]) / calls
-    except Exception:
-        return float("nan")
+    cost_analysis."""
+    return float(lowered.compile().cost_analysis()["bytes accessed"]) / calls
+
+
+def _run_emulated_mesh(prog: str, devices: int) -> dict:
+    """Run ``prog`` in a child pinned to the CPU with ``devices`` forced
+    host devices and return the JSON object it prints last.
+
+    The child emulates a mesh: a parent that has touched JAX holds the
+    accelerator, so a child could not reach it anyway (the on-chip mesh
+    paths run in-process in ``chip_smoke.py --four-chips``). A child that
+    fails or times out fails the run.
+    """
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    out = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                         text=True, timeout=900, env=env, cwd=REPO)
+    if out.returncode:
+        raise RuntimeError(
+            f"emulated-mesh child failed (rc={out.returncode}):\n"
+            f"{out.stderr[-2000:]}")
+    return json.loads(
+        [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
+    )
 
 
 def _bench_step_backend(n, backend, d=4, extra=2.0, seed=0, T=None,
@@ -198,24 +215,19 @@ def _bench_sweep(n=256, d=4, T=300, n_graphs=2, seed=0):
 def _bench_sharded_sweep(n=128, d=3, T=100, devices=4, seed=0):
     """K=256 scenarios in ONE call: single-device vmap vs mesh-sharded.
 
-    Runs in a subprocess so the fake multi-device CPU mesh
-    (``--xla_force_host_platform_device_count``) doesn't leak into this
-    process's jax runtime (same pattern as tests/test_distributed.py). On a
-    real multi-host fleet the same ``mesh=`` argument shards the scenario
-    batch across accelerators; the fake-device walls recorded here pin the
-    single-program/sharded semantics, not a speedup (the devices share one
-    CPU core).
+    Runs in a child pinned to the CPU with ``devices`` forced host devices
+    (:func:`_run_emulated_mesh`), so the row emulates the mesh
+    (``emulated=cpu``): the walls pin the single-program/sharded
+    semantics, not a speedup (the devices share the host's cores).
     """
     prog = textwrap.dedent(f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import json, time
         import numpy as np
         import jax
         from repro.core.graphs import (
             random_strongly_connected, sort_by_dst, stack_edge_lists)
         from repro.core.sweeps import run_pushsum_sweep
-        from repro.launch import compat
+        from repro.distributed.sharding import sweep_mesh
 
         rng = np.random.default_rng({seed})
         adjs = [random_strongly_connected({n}, 0.03, rng) for _ in range(2)]
@@ -237,8 +249,7 @@ def _bench_sharded_sweep(n=128, d=3, T=100, devices=4, seed=0):
             return r, time.perf_counter() - t0, compile_s
 
         r1, single_s, c1 = timed()
-        mesh = compat.make_mesh(({devices},), ("data",))
-        r2, sharded_s, c2 = timed(mesh=mesh)
+        r2, sharded_s, c2 = timed(mesh=sweep_mesh({devices}, 1))
         err = np.abs(np.asarray(r2.err) - np.asarray(r1.err)).max()
         final = np.asarray(r2.err)[:, -1]
         print(json.dumps({{
@@ -248,30 +259,12 @@ def _bench_sharded_sweep(n=128, d=3, T=100, devices=4, seed=0):
             "err_final_max": float(final.max()),
         }}))
     """)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    try:
-        out = subprocess.run([sys.executable, "-c", prog],
-                             capture_output=True, text=True, timeout=900,
-                             env=env, cwd=REPO)
-        failure = out.stderr.strip()[-160:] if out.returncode else None
-    except subprocess.TimeoutExpired:
-        failure = "timeout_900s"
-    if failure is not None:
-        # degrade to a NaN row so the other modules' rows survive; the
-        # json merge skips NaN and --check ignores it
-        return {
-            "name": "pushsum_sweep_sharded256",
-            "us_per_call": float("nan"),
-            "derived": "subprocess_failed;" + failure,
-        }
-    res = json.loads(
-        [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    )
+    res = _run_emulated_mesh(prog, devices)
     return {
         "name": f"pushsum_sweep_sharded{res['K']}",
         "us_per_call": res["sharded_s"] / res["K"] * 1e6,
-        "derived": f"scenarios={res['K']};devices={devices};single_jit=true;"
+        "derived": f"scenarios={res['K']};devices={devices};"
+                   f"emulated=cpu;single_jit=true;"
                    f"sharded_wall_s={res['sharded_s']:.2f};"
                    f"single_dev_wall_s={res['single_s']:.2f};"
                    f"shard_vs_vmap_err={res['shard_vs_vmap_err']:.1e};"
@@ -287,10 +280,10 @@ def _bench_edge_sharded(n=1 << 20, d=1, T=4, devices=8, extra=1.0, seed=0,
     """ONE million-agent scenario on the 2-D (data x graph) mesh.
 
     The graph (E ~ 2e6 edges) is cut into ``devices`` dst-contiguous edge
-    shards (`graphs.partition_edge_list`); each fake CPU device runs the
+    shards (`graphs.partition_edge_list`); each forced CPU device runs the
     unchanged per-shard step and boundary-node receiver partials are
-    psum'd over the mesh ``graph`` axis. Same subprocess pattern as
-    :func:`_bench_sharded_sweep` so the forced device count doesn't leak.
+    psum'd over the mesh ``graph`` axis. Same CPU-pinned child as
+    :func:`_bench_sharded_sweep` (``emulated=cpu``).
     The subprocess also pins the bit-identity contract at small N: the
     shard_map mesh run must match the single-device
     ``vmap(axis_name=)`` emulation of the same cut EXACTLY (same psum
@@ -304,8 +297,6 @@ def _bench_edge_sharded(n=1 << 20, d=1, T=4, devices=8, extra=1.0, seed=0,
     all-gather halo whose re-broadcast leg rides the storage dtype).
     """
     prog = textwrap.dedent(f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import json, time
         import numpy as np
         import jax
@@ -351,26 +342,8 @@ def _bench_edge_sharded(n=1 << 20, d=1, T=4, devices=8, extra=1.0, seed=0,
             "mesh_vs_emul_err": ident,
         }}))
     """)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    try:
-        out = subprocess.run([sys.executable, "-c", prog],
-                             capture_output=True, text=True, timeout=900,
-                             env=env, cwd=REPO)
-        failure = out.stderr.strip()[-160:] if out.returncode else None
-    except subprocess.TimeoutExpired:
-        failure = "timeout_900s"
+    res = _run_emulated_mesh(prog, devices)
     tag = "" if policy is None else f"_{policy}"
-    name = f"pushsum_edge_sharded{tag}_N{n}"
-    if failure is not None:
-        return {
-            "name": name,
-            "us_per_call": float("nan"),
-            "derived": "subprocess_failed;" + failure,
-        }
-    res = json.loads(
-        [l for l in out.stdout.splitlines() if l.startswith("{")][-1]
-    )
     from repro.analysis.roofline import pushsum_halo_wire_bytes
     from repro.core.precision import resolve_policy
     from repro.statics.memory import pushsum_sharded_step_bytes
@@ -382,10 +355,11 @@ def _bench_edge_sharded(n=1 << 20, d=1, T=4, devices=8, extra=1.0, seed=0,
                                    storage_bytes=sb)
     pol = "" if policy is None else f"policy={policy};halo={halo};"
     return {
-        "name": name,
+        "name": f"pushsum_edge_sharded{tag}_N{n}",
         "us_per_call": res["wall_s"] / T * 1e6,   # per-step cost
         "derived": f"E={res['E']};shards={devices};d={d};T={T};"
-                   f"devices={devices};mesh=1x{devices};{pol}"
+                   f"devices={devices};mesh=1x{devices};"
+                   f"emulated=cpu;{pol}"
                    f"budget_bytes={budget};halo_wire_bytes={wire:.0f};"
                    f"mesh_vs_emul_err={res['mesh_vs_emul_err']:.1e};"
                    f"err_final={res['err_final']:.2e};"

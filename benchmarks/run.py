@@ -132,13 +132,10 @@ def main() -> None:
                     help="regression threshold for --check as a ratio "
                          "(default %(default)s = the 25%% gate; CI lanes "
                          "on noisy shared runners pass a looser value)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable jax's persistent compilation cache rooted "
-                         "here (the CI bench lane persists this directory "
-                         "across runs; see benchmarks/compile_cache.py)")
     args = ap.parse_args()
-    if args.compile_cache:
-        compile_cache.enable(args.compile_cache)
+    # persistent compile cache: $JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax-cache (see benchmarks/compile_cache.py)
+    compile_cache.enable()
     if args.only and args.only not in {t for t, _ in MODULES}:
         # a typo'd tag must fail loudly, not run zero modules and let a
         # --check gate pass green on an empty measurement set

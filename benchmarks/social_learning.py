@@ -81,20 +81,15 @@ def _time_run(model, rt, M, T, backend, policy=None):
 def _bytes_per_step(model, rt, M, T, backend, policy=None) -> float:
     """Compiled per-step 'bytes accessed' of the fused engine — the number
     the precision policy halves (cost_analysis over an explicit jit of the
-    same call; NaN when the backend doesn't report it)."""
+    same call)."""
     from repro.core.plan import ExecutionPlan
 
     fn = jax.jit(lambda rt_: run_social_runtime(
         model, rt_, M, T, seed=0,
         plan=ExecutionPlan(backend=backend, store="final", policy=policy),
     ).beliefs)
-    try:
-        cost = fn.lower(rt).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        return float(cost["bytes accessed"]) / T
-    except Exception:
-        return float("nan")
+    cost = fn.lower(rt).compile().cost_analysis()
+    return float(cost["bytes accessed"]) / T
 
 
 def _step_rows(smoke: bool):

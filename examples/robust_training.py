@@ -40,11 +40,11 @@ from repro.distributed.trainer import (
     TrainConfig, make_train_step, param_spread,
     replicate_for_workers, worker_opt_init,
 )
-from repro.launch import compat
 from repro.models import model as M
 from repro.optim import AdamWConfig
 
-mesh = compat.make_mesh((2, args.devices // 4, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, args.devices // 4, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 n_workers = 2 * (args.devices // 4)
 
 cfg = get_config("paper_sim")            # ~100M params
@@ -71,7 +71,7 @@ factory, _ = make_train_step(tc, mesh)
 pw = replicate_for_workers(params, n_workers)
 ow = worker_opt_init(pw)
 
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step = jax.jit(factory(pw))
     spread_fn = jax.jit(param_spread)  # one executable, ordered collectives
     for s in range(args.steps):

@@ -285,7 +285,7 @@ def sparse_pushsum_step(
     edge set when running edge-partitioned (below).
 
     **Edge-partitioned mode** (``graph_axis=``): inside a
-    ``compat.shard_map`` (or an emulating ``vmap(axis_name=...)``) over a
+    ``jax.shard_map`` (or an emulating ``vmap(axis_name=...)``) over a
     mesh graph axis, ``src``/``dst``/``valid``/``mask`` and the per-edge
     state carry only this device's (E_shard,) slice of a
     :func:`repro.core.graphs.partition_edge_list` layout while node state

@@ -402,8 +402,9 @@ def _sweep_sharded(mesh: Mesh, data_axis: str, T: int, B: int, backend: str,
     executable per combo (Mesh is hashable), mirroring ``_sweep_compiled``'s
     retrace-free behaviour. ``has_faults``/``has_async`` add the batched
     FaultModel / AsyncModel arguments (sharded over ``data_axis`` like
-    every scenario coordinate)."""
-    from repro.launch import compat
+    every scenario coordinate). The body is manual over every mesh axis,
+    not just ``data_axis``: a Pallas kernel cannot sit in an
+    auto-partitioned region, and the other axes only see replicas."""
 
     base = functools.partial(_sweep_body, T=T, B=B, backend=backend,
                              policy=policy, dst_sorted=dst_sorted)
@@ -421,12 +422,11 @@ def _sweep_sharded(mesh: Mesh, data_axis: str, T: int, B: int, backend: str,
     if has_async:
         in_specs += (AsyncModel(
             *([P(data_axis)] * len(AsyncModel._fields))),)
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(data_axis), P(data_axis), P(data_axis)),
-        axis_names=frozenset({data_axis}),
         check_vma=False,
     )
     return jax.jit(sharded)
@@ -575,7 +575,6 @@ def _sweep_sharded_2d(mesh: Mesh, data_axis: str, graph_axis: str,
     graph-device, combined per round by the psum inside the body. Outputs
     are graph-replicated, so their specs name only the data axis."""
     from repro.distributed.sharding import sweep_specs
-    from repro.launch import compat
 
     specs = sweep_specs(data_axis, graph_axis)
     n_shards = int(mesh.shape[graph_axis])
@@ -590,7 +589,7 @@ def _sweep_sharded_2d(mesh: Mesh, data_axis: str, graph_axis: str,
     if has_faults:
         in_specs += (FaultModel(
             *([specs["scenario"]] * len(FaultModel._fields))),)
-    sharded = compat.shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
@@ -1111,15 +1110,12 @@ def run_byzantine_grid(
         )
         batched = jax.vmap(single)
         if mesh is not None:
-            from repro.launch import compat
-
             spec = P(data_axis)
-            batched = compat.shard_map(
+            batched = jax.shard_map(
                 batched,
                 mesh=mesh,
                 in_specs=(spec, ByzRuntime(*([spec] * len(ByzRuntime._fields)))),
                 out_specs=ByzantineResult(r=spec, decisions=spec),
-                axis_names=frozenset({data_axis}),
                 check_vma=False,
             )
         fn = _BYZ_GRID_COMPILED[cache_key] = jax.jit(batched)
@@ -1221,8 +1217,6 @@ def _social_sweep_fn(mesh, data_axis, *, truth, M, T, store, backend,
         body = base
 
     if mesh is not None:
-        from repro.launch import compat
-
         spec = P(data_axis)
         in_specs = (
             spec,
@@ -1236,12 +1230,11 @@ def _social_sweep_fn(mesh, data_axis, *, truth, M, T, store, backend,
         if has_async:
             in_specs += (AsyncModel(
                 *([spec] * len(AsyncModel._fields))),)
-        body = compat.shard_map(
+        body = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(spec, spec),
-            axis_names=frozenset({data_axis}),
             check_vma=False,
         )
     fn = _SOCIAL_COMPILED[key] = jax.jit(body)
@@ -1544,8 +1537,6 @@ def _hps_sweep_fn(mesh, data_axis, *, T, store, backend, policy=None,
         body = base
 
     if mesh is not None:
-        from repro.launch import compat
-
         spec = P(data_axis)
         in_specs = (
             spec,
@@ -1558,12 +1549,11 @@ def _hps_sweep_fn(mesh, data_axis, *, T, store, backend, policy=None,
         if has_async:
             in_specs += (AsyncModel(
                 *([spec] * len(AsyncModel._fields))),)
-        body = compat.shard_map(
+        body = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=(spec, spec),
-            axis_names=frozenset({data_axis}),
             check_vma=False,
         )
     fn = _HPS_COMPILED[key] = jax.jit(body)

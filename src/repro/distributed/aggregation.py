@@ -82,15 +82,11 @@ class AggregatorConfig:
                                     # the Byzantine guarantee is unchanged)
 
 
-def _axis_size(name) -> int:
-    from repro.launch.compat import axis_size
-    return axis_size(name)
-
-
 def _worker_index(data_axis: str, pod_axis: str | None) -> jnp.ndarray:
     idx = jax.lax.axis_index(data_axis)
     if pod_axis is not None:
-        idx = jax.lax.axis_index(pod_axis) * _axis_size(data_axis) + idx
+        idx = (jax.lax.axis_index(pod_axis) * jax.lax.axis_size(data_axis)
+               + idx)
     return idx
 
 
@@ -115,8 +111,8 @@ def agg_pushsum(grads: Params, cfg: AggregatorConfig, data_axis, pod_axis, key):
     Returns each worker's z/m estimate (approximate mean; the residual is
     the paper's consensus error, measurable as cross-worker disagreement).
     """
-    W = _axis_size(data_axis)
-    n_pods = _axis_size(pod_axis) if pod_axis else 1
+    W = jax.lax.axis_size(data_axis)
+    n_pods = jax.lax.axis_size(pod_axis) if pod_axis else 1
     fwd = [(i, (i + 1) % W) for i in range(W)]
 
     leaves, treedef = jax.tree_util.tree_flatten(grads)
@@ -224,7 +220,7 @@ def agg_pushsum_sparse(
     axes = (pod_axis, data_axis) if pod_axis else (data_axis,)
     W = 1
     for a in axes:
-        W *= _axis_size(a)
+        W *= jax.lax.axis_size(a)
     adj = random_strongly_connected(
         W, cfg.graph_extra_edge_prob, np.random.default_rng(cfg.graph_seed)
     )
@@ -287,7 +283,7 @@ def agg_hierarchical_trim(
 
     With n_pods <= 2F the cross-pod trim degenerates to a mean — exactly the
     paper's Assumption 5 constraint (need >= 2F+1 sub-networks to trim)."""
-    n_pods = _axis_size(pod_axis) if pod_axis else 1
+    n_pods = jax.lax.axis_size(pod_axis) if pod_axis else 1
 
     def trim_leaf(g):
         gf = g.astype(jnp.float32).reshape(-1)
@@ -324,7 +320,7 @@ def agg_trimmed_sharded(
     axes = [a for a in (pod_axis, data_axis) if a]
     W = 1
     for a in axes:
-        W *= _axis_size(a)
+        W *= jax.lax.axis_size(a)
 
     def trim_leaf(g):
         shape = g.shape
@@ -338,8 +334,8 @@ def agg_trimmed_sharded(
         # all_to_all over the (possibly two) worker axes in sequence
         recv = stripes
         if pod_axis:
-            n_pod = _axis_size(pod_axis)
-            n_dat = _axis_size(data_axis)
+            n_pod = jax.lax.axis_size(pod_axis)
+            n_dat = jax.lax.axis_size(data_axis)
             # (pod, data, stripe) exchange: first flatten stripes per axis
             recv = recv.reshape(n_pod, n_dat, -1)
             recv = jax.lax.all_to_all(recv, pod_axis, split_axis=0,

@@ -198,14 +198,12 @@ def sweep_mesh(
     scenario axis (one scenario batch per device row), ``graph_axis``
     shards the edge index of each scenario into ``n_graph`` dst-contiguous
     shards (:func:`repro.core.graphs.partition_edge_list`). ``n_data *
-    n_graph`` must not exceed the available device count. Built through
-    :func:`repro.launch.compat.make_mesh` so the same call works across the
-    jax versions the repo supports.
+    n_graph`` must not exceed the available device count. Both axes are
+    ``AxisType.Auto``: the engines shard_map over them explicitly.
     """
-    from repro.launch import compat
-
-    return compat.make_mesh(
-        (n_data, n_graph), (data_axis, graph_axis), devices=devices
+    return jax.make_mesh(
+        (n_data, n_graph), (data_axis, graph_axis),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2, devices=devices,
     )
 
 

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.launch import compat
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 from .aggregation import AGGREGATORS, AggregatorConfig
@@ -124,16 +123,11 @@ def _make_gspmd_step(tc: TrainConfig, mesh: Mesh):
 
     def train_step_factory(params_like, batch_keys=("tokens", "labels")):
         pspecs, _, _ = shardings(params_like, batch_keys)
-        # Pinning the grad accumulator to the param layout is a memory
-        # optimization only; jax 0.4.x's XLA CPU SPMD partitioner miscompiles
-        # the constrained backward pass (grads off by O(1) relative), so the
-        # constraint is applied on modern jax exclusively.
-        gshard = None
-        if compat.HAS_AXIS_TYPE:
-            gshard = jax.tree_util.tree_map(
-                lambda s: NamedSharding(mesh, s), pspecs,
-                is_leaf=lambda x: isinstance(x, P),
-            )
+        # pin the grad accumulator to the param layout (a memory optimization)
+        gshard = jax.tree_util.tree_map(
+            lambda s: NamedSharding(mesh, s), pspecs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
 
         def train_step(params, opt_state, batch):
             loss, grads = _grads_microbatched(
@@ -210,7 +204,7 @@ def _make_robust_step(tc: TrainConfig, mesh: Mesh):
             lambda s: _manual_only(s, manual), tree,
             is_leaf=lambda x: isinstance(x, P),
         )
-        return compat.shard_map(
+        return jax.shard_map(
             per_worker,
             mesh=mesh,
             in_specs=(strip(pspecs), strip(ospecs), strip(bspec), P()),

@@ -4,13 +4,15 @@
   coordinate-wise over the worker axis (the paper's scalar-dynamics trick
   vectorized over every gradient coordinate).
 - ``pushsum_edge`` — fused edge-scatter for the sparse robust push-sum
-  core: gather ``sigma[src]``, mask-latch, and the per-receiver increment
-  sum in one streaming pass over a dst-sorted edge index (Algorithm 1's
+  core: mask-latch and per-receiver run sums of the increments in one
+  streaming pass over a dst-sorted edge index, between an XLA gather of
+  ``sigma[src]`` and an XLA ``segment_sum`` finish (Algorithm 1's
   per-round hot path at N ~ 1e5).
-- ``byz_trim`` — fused neighbor trim-gather for the sparse Byzantine
-  gossip core: gather over a padded neighbor list, Byzantine-message
-  substitution, and the F-round extremes-extraction trim in one streaming
-  pass over receiver blocks (Algorithm 2's per-round hot path).
+- ``byz_trim`` — fused neighbor trim for the sparse Byzantine gossip
+  core: after an XLA gather over a padded neighbor list with
+  Byzantine-message substitution, the F-round extremes-extraction trim
+  in one streaming pass over receiver blocks (Algorithm 2's per-round
+  hot path).
 - ``social_innov`` — fused innovation + belief step for the Algorithm 3
   social-learning engine: inverse-CDF signal sampling, the log-likelihood
   table gather, dual accumulation, and the KL-proximal softmax belief in

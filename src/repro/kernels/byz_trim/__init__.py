@@ -19,9 +19,10 @@ O(N deg_max m^2 F) with nothing larger than (N, deg_max, m^2) live.
 traced F, which is what batched (topology, F) sweeps vmap over); :mod:`.ops`
 hosts the ``backend="auto"|"xla"|"pallas"`` dispatch used by
 :func:`repro.core.byzantine.make_byzantine_scan`; :mod:`.byz_trim` is the
-fused Pallas kernel (F-round extremes extraction, no sort). The dense
-``trimmed_neighbor_mean`` in :mod:`repro.core.byzantine` is retained purely
-as the equivalence oracle for tests.
+fused Pallas trim (F-round extremes extraction, no sort) behind an XLA
+gather of the slot values. The dense ``trimmed_neighbor_mean`` in
+:mod:`repro.core.byzantine` is retained purely as the equivalence oracle
+for tests.
 """
 from .ops import BACKENDS, resolve_backend, trim_gather, trim_gather_pairs
 from .ref import trim_gather_ref

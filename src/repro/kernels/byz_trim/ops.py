@@ -5,7 +5,8 @@ Byzantine core calls per gossip round:
 
 ``"xla"``     — gather + sort + rank mask (:mod:`.ref`); runs anywhere and
                 accepts a *traced* F (dynamic-F scenario batches).
-``"pallas"``  — the fused O(F * deg) extraction kernel (:mod:`.byz_trim`);
+``"pallas"``  — XLA gather + the O(F * deg) extraction kernel
+                (:mod:`.byz_trim`);
                 compiled on TPU, interpreter mode elsewhere (equivalence
                 testing only — interpret mode is not a fast path). Requires
                 a static int F (the extraction loop unrolls).

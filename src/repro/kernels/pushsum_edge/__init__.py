@@ -6,10 +6,11 @@ One push-sum round's hot half is, per directed edge e (src -> dst):
     recv[v]   += rho_new[e] - rho[e]  for v = dst[e]       (integration)
 
 XLA lowers this to a gather plus a generic ``segment_sum`` scatter per
-round; with the edge index pre-sorted by ``dst``
-(:func:`repro.core.graphs.sort_by_dst`) the whole thing is one streaming
-pass over E with contiguous per-receiver segments, which is what the
-Pallas kernel in :mod:`.pushsum_edge` implements. :mod:`.ref` is the
+round. With the edge index pre-sorted by ``dst``
+(:func:`repro.core.graphs.sort_by_dst`) each receiver's increments form
+one contiguous run, and the Pallas kernel in :mod:`.pushsum_edge` streams
+E once to latch and reduce every run to one partial sum; the gather before
+it and the per-receiver finish after it stay in XLA. :mod:`.ref` is the
 always-available XLA fallback and the equivalence oracle; :mod:`.ops`
 hosts the ``backend="auto"|"xla"|"pallas"`` dispatch used by
 :func:`repro.core.pushsum.sparse_pushsum_step`.

@@ -41,8 +41,8 @@ def edge_scatter(
     """Fused mask-latch + per-receiver increment sum; see package docstring.
 
     Returns ``(rho_new (E, D), recv (N, D))``. ``indices_sorted=True``
-    promises a dst-sorted edge index, letting the XLA lowering drop one
-    argsort (the Pallas kernel already streams in dst order and ignores it).
+    promises a dst-sorted edge index, letting either lowering's
+    ``segment_sum`` drop one argsort.
     ``accum_dtype`` names the dtype of the ``recv`` reduction (the
     precision policy's accum slot — see :mod:`repro.core.precision`);
     ``None`` keeps the input dtype.
@@ -53,5 +53,5 @@ def edge_scatter(
                                 accum_dtype=accum_dtype)
     return edge_scatter_pallas(
         sigma, rho, live, src, dst, block_e=block_e, interpret=interpret,
-        accum_dtype=accum_dtype,
+        accum_dtype=accum_dtype, indices_sorted=indices_sorted,
     )

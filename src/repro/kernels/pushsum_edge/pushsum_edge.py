@@ -1,48 +1,46 @@
-"""Pallas kernel: fused push-sum edge scatter over a dst-sorted edge index.
+"""Pallas kernel: fused push-sum mask-latch + per-run increment sums.
 
-One call covers the whole delivery + integration half of a robust push-sum
-round (Su '18 Alg. 1 lines 6-11) in a single streaming pass over the edge
-list — gather ``sigma[src]``, latch it into ``rho`` on live edges, and
-accumulate the per-receiver sum of increments — replacing XLA's gather +
-generic scatter lowering of ``jax.ops.segment_sum``.
+One call covers the delivery + integration half of a robust push-sum round
+(Su '18 Alg. 1 lines 6-11) over a dst-sorted edge list: latch the sender's
+staged cumulative into ``rho`` on live edges, and reduce each receiver's
+run of increments ``rho_new - rho`` to one partial sum.
 
 Design (see /opt/skills/guides/pallas_guide.md)
 -----------------------------------------------
-* Grid: 1-D over edge blocks of ``block_e`` edges. TPU grids execute
-  sequentially on a core, which the kernel exploits: ``recv`` is a full
-  (N, D) VMEM-resident output with a constant index map, zeroed at block 0
-  and accumulated into by every block (the matmul-K-loop accumulator
-  pattern). ``sigma`` (N, D) is likewise resident — at the target workload
-  (N ~ 1e5, D = d+1 with d small) it is a few MB, well under VMEM.
+* Data-dependent addressing stays in XLA, the streaming arithmetic in the
+  kernel. The row gather ``sigma[src]`` runs as an XLA gather before the
+  call and the kernel receives the gathered rows as a blocked operand;
+  the per-receiver finish is a sorted ``segment_sum`` over the kernel's
+  run-end partials after it. Mosaic lowers neither an in-kernel row gather
+  nor a data-dependent scatter, and nothing node-indexed has to be VMEM
+  resident: every operand is a block of the edge axis.
+* Layout: edges on the 128-wide lane axis, the feature axis D = d+1 on
+  sublanes — ``(D, block_e)`` tiles, so a small D costs sublane padding
+  (5 -> 8) instead of lane padding (5 -> 128). ``live`` and ``dst`` ride as
+  ``(1, block_e)`` rows. The wrapper transposes at the call boundary.
 * Within a block the per-receiver reduction uses the *sorted-run* trick:
-  with edges pre-sorted by ``dst`` (:func:`repro.core.graphs.sort_by_dst`)
-  each receiver's edges form one contiguous run, so a *segmented* scan
-  along the edge axis (log2(block_e) flag-carrying Hillis-Steele steps,
-  pure VPU shift+add) leaves each run's inclusive sum at its last edge and
-  the scatter touches each receiver row exactly once per block:
-  ``recv[v] += seg[end]``. Unique indices are the fast path Mosaic can
-  vectorize — the thing XLA's sorted-scatter lowering never recovers on
-  its own. The scan is segmented rather than a plain cumsum with boundary
-  differences precisely because push-sum's z/m ratio amplifies absolute
-  error by 1/m (m decays geometrically): subtracting two large
-  cross-segment prefixes to recover a small segment sum cancels
-  catastrophically, while segment-local partial sums keep the error at
-  the run's own reduction scale.
+  each receiver's edges form one contiguous run of lanes, so a
+  *segmented* inclusive scan (log2(block_e) flag-carrying Hillis-Steele
+  steps, ``pltpu.roll`` + select + add) leaves each run's sum at its last
+  lane and the kernel emits only those run-end partials (zero elsewhere).
+  The scan is segmented rather than a plain cumsum with boundary
+  differences because push-sum's z/m ratio amplifies absolute error by
+  1/m (m decays geometrically): subtracting two large cross-segment
+  prefixes to recover a small segment sum cancels catastrophically, while
+  segment-local partial sums keep the error at the run's own scale.
 * Correctness does NOT require sortedness: an unsorted index just breaks
-  runs into more fragments, each accumulated with scatter-add semantics.
-  Sorting is purely what collapses the update count to O(distinct dst).
-* A run spanning a block boundary is finished by the next block: the first
-  edge of every block opens a fresh run (``c_prev[0] == 0``), and the
-  trailing partial sum was already flushed by the previous block's
-  ``is_end[-1]`` update, so the two partials add up in ``recv``.
-* Padding edges (to a multiple of ``block_e``) are appended with
-  ``live=False`` and ``dst = N - 1``: their increment is exactly zero, so
-  the only effect is a zero added to the last receiver row.
+  runs into more fragments, each with its own run-end partial, and the
+  ``segment_sum`` finish adds fragments of one receiver together. A run
+  that spans a block boundary ends at the block's last lane and reopens
+  at the next block's first lane in the same way.
+* Padding edges (to a multiple of ``block_e``) carry ``live = 0`` and the
+  out-of-range receiver ``N``, so the last real run still ends on a real
+  lane; the pad lanes are sliced off before the finish.
 
-The feature axis D = d+1 is small for consensus workloads, which
-underutilizes the 128-wide lanes; the streaming axis (edges) carries the
-throughput. ``interpret=None`` auto-selects interpreter mode off-TPU so CPU
-CI validates the identical program (tests/test_pushsum_edge_kernel.py).
+Compiled on a TPU, ``block_e`` must be a multiple of 128 (the lane width)
+or at least E; interpret mode accepts any block. ``interpret=None``
+auto-selects interpreter mode off-TPU so CPU CI validates the identical
+program (tests/test_pushsum_edge_kernel.py).
 """
 from __future__ import annotations
 
@@ -51,65 +49,58 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["edge_scatter_pallas"]
 
 
-def _segmented_cumsum(delta, is_first):
-    """Inclusive scan over the edge axis that restarts at run boundaries.
+def _segmented_cumsum(v, is_first):
+    """Inclusive scan along lanes that restarts at run starts.
 
-    Flag-carrying Hillis-Steele: at stride s, position i absorbs i-s only
-    if no segment start lies in (i-s, i]; flags OR upward so the check
-    stays O(1) per step. log2(BE) static steps, shift+add only, and every
-    partial sum is segment-local (no cross-segment cancellation).
+    ``v`` is (D, BE), ``is_first`` a (1, BE) int32 flag row. Flag-carrying
+    Hillis-Steele: at stride s, lane i absorbs lane i-s only if no run
+    start lies in (i-s, i]; flags OR upward so the check stays O(1) per
+    step. log2(BE) static steps, every partial sum segment-local.
     """
-    v, f = delta, is_first
-    n = delta.shape[0]
+    n = v.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+    f = is_first
     s = 1
     while s < n:
-        v_prev = jnp.concatenate([jnp.zeros_like(v[:s]), v[:-s]], axis=0)
-        f_prev = jnp.concatenate(
-            [jnp.ones((min(s, n),), jnp.bool_), f[:-s]], axis=0
-        )
-        v = jnp.where(f[:, None], v, v + v_prev)
-        f = f | f_prev
+        head = lane < s                          # no lane i-s to absorb
+        v_prev = pltpu.roll(v, s, 1)             # lane i <- lane i-s
+        f_prev = pltpu.roll(f, s, 1)
+        v = jnp.where((f != 0) | head, v, v + v_prev)
+        f = jnp.where(head, 1, f | f_prev)
         s *= 2
     return v
 
 
-def _kernel(sigma_ref, rho_ref, live_ref, src_ref, dst_ref,
-            rho_out_ref, recv_ref):
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        recv_ref[...] = jnp.zeros_like(recv_ref)
-
-    sigma = sigma_ref[...]                      # (N, D) resident
-    rho = rho_ref[...]                          # (BE, D)
-    live = live_ref[...]                        # (BE,)
-    src = src_ref[...]                          # (BE,)
-    dst = dst_ref[...]                          # (BE,)
+def _kernel(sent_ref, rho_ref, live_ref, dst_ref, rho_out_ref, upd_ref):
+    rho = rho_ref[...]                           # (D, BE)
+    live = live_ref[...] != 0                    # (1, BE)
 
     # --- mask-latch: live edges adopt the sender's staged cumulative ---
-    gathered = jnp.take(sigma, src, axis=0)     # (BE, D)
-    rho_new = jnp.where(live[:, None], gathered, rho)
+    rho_new = jnp.where(live, sent_ref[...], rho)
     rho_out_ref[...] = rho_new
 
-    # --- per-receiver segment sum of increments via sorted runs ---
-    # the accumulator dtype is recv's (the policy's accum slot): latched
+    # --- run-end partial sums of the increments ---
+    # the accumulator dtype is upd's (the policy's accum slot): latched
     # state streams at storage precision, the reduction runs full-precision
-    acc = recv_ref.dtype
+    acc = upd_ref.dtype
     delta = rho_new.astype(acc) - rho.astype(acc)  # zero on dead/pad edges
-    change = dst[1:] != dst[:-1]                # (BE-1,) run boundaries
-    one = jnp.ones((1,), jnp.bool_)
-    is_end = jnp.concatenate([change, one])     # last edge of each run
-    is_first = jnp.concatenate([one, change])   # first edge of each run
-    seg = _segmented_cumsum(delta, is_first)    # run-local inclusive sums
-    upd = jnp.where(is_end[:, None], seg, 0.0)
-    recv_ref[...] = recv_ref[...].at[dst].add(upd)
+    dst = dst_ref[...]                           # (1, BE)
+    be = dst.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, be), 1)
+    is_first = (lane == 0) | (dst != pltpu.roll(dst, 1, 1))
+    is_end = (lane == be - 1) | (dst != pltpu.roll(dst, be - 1, 1))
+    seg = _segmented_cumsum(delta, is_first.astype(jnp.int32))
+    upd_ref[...] = jnp.where(is_end, seg, 0.0).astype(acc)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_e", "interpret", "accum_dtype")
+    jax.jit,
+    static_argnames=("block_e", "interpret", "accum_dtype", "indices_sorted"),
 )
 def edge_scatter_pallas(
     sigma: jnp.ndarray,   # (N, D) staged cumulative send per node
@@ -121,47 +112,50 @@ def edge_scatter_pallas(
     block_e: int = 4096,
     interpret: bool | None = None,
     accum_dtype: str | None = None,
+    indices_sorted: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fused edge scatter -> ``(rho_new (E, D), recv (N, D))``.
 
     Matches :func:`repro.kernels.pushsum_edge.ref.edge_scatter_ref` to fp32
     reduction order. E is padded to a multiple of ``block_e`` with inert
-    edges; the pad rows are sliced off ``rho_new``. ``accum_dtype`` names
-    the dtype of the ``recv`` accumulator (the precision policy's accum
-    slot; casts happen at the kernel block boundary) — ``None`` keeps the
-    input dtype, the pre-policy program.
+    edges; the pad lanes are sliced off. ``accum_dtype`` names the dtype
+    of the increment reduction and of ``recv`` (the precision policy's
+    accum slot) — ``None`` keeps the input dtype, the pre-policy program.
+    ``indices_sorted=True`` promises a dst-sorted index to the
+    ``segment_sum`` finish.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     acc = sigma.dtype if accum_dtype is None else jnp.dtype(accum_dtype)
     n, D = sigma.shape
     E = rho.shape[0]
+    block_e = min(block_e, max(E, 1))
     pad = (-E) % block_e
+    sent_t = sigma[src].T                                    # XLA gather
+    rho_t = rho.T
+    live_r = live.astype(jnp.int32)[None, :]                 # (1, E)
+    dst_r = dst[None, :]
     if pad:
-        rho = jnp.pad(rho, ((0, pad), (0, 0)))
-        live = jnp.pad(live, (0, pad))                       # False
-        src = jnp.pad(src, (0, pad))                         # node 0
-        dst = jnp.pad(dst, (0, pad), constant_values=n - 1)  # inert target
+        sent_t = jnp.pad(sent_t, ((0, 0), (0, pad)))
+        rho_t = jnp.pad(rho_t, ((0, 0), (0, pad)))
+        live_r = jnp.pad(live_r, ((0, 0), (0, pad)))         # dead
+        dst_r = jnp.pad(dst_r, ((0, 0), (0, pad)), constant_values=n)
     Ep = E + pad
 
-    rho_new, recv = pl.pallas_call(
+    edge_block = pl.BlockSpec((D, block_e), lambda i: (0, i))
+    row_block = pl.BlockSpec((1, block_e), lambda i: (0, i))
+    rho_new_t, upd_t = pl.pallas_call(
         _kernel,
         grid=(Ep // block_e,),
-        in_specs=[
-            pl.BlockSpec((n, D), lambda i: (0, 0)),          # sigma resident
-            pl.BlockSpec((block_e, D), lambda i: (i, 0)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-            pl.BlockSpec((block_e,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_e, D), lambda i: (i, 0)),
-            pl.BlockSpec((n, D), lambda i: (0, 0)),          # accumulator
-        ],
+        in_specs=[edge_block, edge_block, row_block, row_block],
+        out_specs=[edge_block, edge_block],
         out_shape=[
-            jax.ShapeDtypeStruct((Ep, D), rho.dtype),
-            jax.ShapeDtypeStruct((n, D), acc),
+            jax.ShapeDtypeStruct((D, Ep), rho.dtype),
+            jax.ShapeDtypeStruct((D, Ep), acc),
         ],
         interpret=interpret,
-    )(sigma, rho, live, src, dst)
-    return rho_new[:E], recv
+    )(sent_t, rho_t, live_r, dst_r)
+    recv = jax.ops.segment_sum(
+        upd_t[:, :E].T, dst, num_segments=n, indices_are_sorted=indices_sorted
+    )
+    return rho_new_t[:, :E].T, recv

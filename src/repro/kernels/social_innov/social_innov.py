@@ -9,22 +9,32 @@ add, softmax) each reading/writing (N, ·) HBM intermediates per scan step.
 
 Design (see /opt/skills/guides/pallas_guide.md)
 -----------------------------------------------
-* Grid: 1-D over agent blocks of ``block_n`` rows. Every input is
+* Grid: 1-D over agent blocks of ``block_n`` agents. Every input is
   block-mapped — nothing is resident across blocks, so the kernel streams:
   per block it touches O(block_n * (m S + S + m)) VMEM and emits
   O(block_n * m). No cross-block state means any grid order is legal.
+* Layout: agents on the 128-wide lane axis, the small hypothesis and
+  alphabet axes on sublanes and the leading axis — ``z`` and ``mu`` are
+  ``(m, block_n)`` tiles, ``cdf`` is ``(S, block_n)``, the tables are
+  ``(m, S, block_n)``. Agent-major ``(block_n, m, S)`` blocks would pad
+  every (m, S) pair to a full (8, 128) tile and overflow VMEM at
+  N ~ 1e5. The wrapper transposes at the call boundary.
 * The per-agent gather ``log_tables[j, :, sig[j]]`` is lowered as a one-hot
   contraction over the alphabet axis (``iota_S == sig`` mask + sum) rather
   than a dynamic gather: S is small (4-16 for the paper's models), the
-  one-hot select is pure VPU, and Mosaic vectorizes it where a per-row
+  one-hot select is pure VPU, and Mosaic vectorizes it where a per-agent
   dynamic slice would serialize.
-* The softmax runs on the block tile with the standard max-subtraction;
-  hypotheses m is small so the reduction axis is cheap — the streaming axis
-  (agents) carries the throughput, as with the other consensus kernels.
+* The softmax runs over the hypothesis axis of the block tile with the
+  standard max-subtraction; m is small so the reduction is cheap — the
+  streaming axis (agents) carries the throughput, as with the other
+  consensus kernels.
 * Padding agents (to a multiple of ``block_n``) carry ``mass = 0`` /
-  ``u = 0`` / all-zero table rows: their ``sig`` is 0, their ``z_new`` row
-  is ``z + 0`` and the softmax of a zero row is uniform — finite, inert,
-  and sliced off.
+  ``u = 0`` / all-zero table columns: their ``sig`` is 0, their ``z_new``
+  column is ``z + 0`` and the softmax of a zero column is uniform — finite,
+  inert, and sliced off.
+
+Compiled on a TPU, ``block_n`` must be a multiple of 128 (the lane width)
+or at least N; interpret mode accepts any block.
 
 ``interpret=None`` auto-selects interpreter mode off-TPU so CPU CI
 validates the identical program (tests/test_social_engine.py).
@@ -41,32 +51,33 @@ __all__ = ["innovation_pallas"]
 
 
 def _kernel(z_ref, mass_ref, u_ref, cdf_ref, lt_ref, z_out_ref, mu_ref):
-    z = z_ref[...]                               # (BN, m)
-    mass = mass_ref[...]                         # (BN,)
-    u = u_ref[...]                               # (BN,)
-    cdf = cdf_ref[...]                           # (BN, S)
-    lt = lt_ref[...]                             # (BN, m, S)
+    z = z_ref[...]                               # (m, BN)
+    mass = mass_ref[...]                         # (1, BN)
+    u = u_ref[...]                               # (1, BN)
+    cdf = cdf_ref[...]                           # (S, BN)
+    lt = lt_ref[...]                             # (m, S, BN)
 
     # --- inverse-CDF categorical sample per agent; clamp because an fp32
     # cumsum can end below 1.0 (u >= cdf[-1] must map to the last letter) ---
-    s_max = cdf.shape[1] - 1
-    sig = jnp.minimum((u[:, None] > cdf).sum(axis=-1), s_max).astype(jnp.int32)
+    s_max = cdf.shape[0] - 1
+    below = (u > cdf).astype(jnp.int32)          # (S, BN)
+    sig = jnp.minimum(below.sum(axis=0, keepdims=True), s_max)  # (1, BN)
 
-    # --- (m,) log-likelihood row gather as a one-hot contraction over S ---
-    s_iota = jax.lax.broadcasted_iota(jnp.int32, lt.shape, 2)
-    onehot = s_iota == sig[:, None, None]
+    # --- (m,) log-likelihood gather as a one-hot contraction over S ---
+    s_iota = jax.lax.broadcasted_iota(jnp.int32, lt.shape, 1)
+    onehot = s_iota == sig[None]
     acc = mu_ref.dtype                           # the policy accum slot
-    loglik = jnp.where(onehot, lt.astype(acc), 0.0).sum(axis=-1)  # (BN, m)
+    loglik = jnp.where(onehot, lt.astype(acc), 0.0).sum(axis=1)  # (m, BN)
 
     # --- dual accumulation + KL-proximal belief (softmax of z/m) ---
     # the accumulation and softmax run in the accum slot; z_new is downcast
     # to the persistent storage dtype on the way out
     z_new = z.astype(acc) + loglik
     z_out_ref[...] = z_new.astype(z_out_ref.dtype)
-    ratio = z_new / jnp.maximum(mass.astype(acc), 1e-30)[:, None]
-    shifted = ratio - ratio.max(axis=-1, keepdims=True)
+    ratio = z_new / jnp.maximum(mass.astype(acc), 1e-30)
+    shifted = ratio - ratio.max(axis=0, keepdims=True)
     e = jnp.exp(shifted)
-    mu_ref[...] = e / e.sum(axis=-1, keepdims=True)
+    mu_ref[...] = e / e.sum(axis=0, keepdims=True)
 
 
 @functools.partial(
@@ -99,32 +110,32 @@ def innovation_pallas(
     S = cdf.shape[1]
     block_n = min(block_n, max(n, 1))
     pad = (-n) % block_n
+    z_t = z.T                                          # (m, N)
+    mass_r = mass[None, :]                             # (1, N)
+    u_r = u[None, :]
+    cdf_t = cdf.T                                      # (S, N)
+    lt_t = jnp.transpose(log_tables, (1, 2, 0))        # (m, S, N)
     if pad:
-        z = jnp.pad(z, ((0, pad), (0, 0)))
-        mass = jnp.pad(mass, (0, pad))
-        u = jnp.pad(u, (0, pad))
-        cdf = jnp.pad(cdf, ((0, pad), (0, 0)))
-        log_tables = jnp.pad(log_tables, ((0, pad), (0, 0), (0, 0)))
+        z_t = jnp.pad(z_t, ((0, 0), (0, pad)))
+        mass_r = jnp.pad(mass_r, ((0, 0), (0, pad)))
+        u_r = jnp.pad(u_r, ((0, 0), (0, pad)))
+        cdf_t = jnp.pad(cdf_t, ((0, 0), (0, pad)))
+        lt_t = jnp.pad(lt_t, ((0, 0), (0, 0), (0, pad)))
     n_pad = n + pad
 
-    z_new, mu = pl.pallas_call(
+    def agents(*lead):
+        return pl.BlockSpec(lead + (block_n,),
+                            lambda i: (0,) * len(lead) + (i,))
+
+    z_new_t, mu_t = pl.pallas_call(
         _kernel,
         grid=(n_pad // block_n,),
-        in_specs=[
-            pl.BlockSpec((block_n, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n, S), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, m, S), lambda i: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, m), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, m), lambda i: (i, 0)),
-        ],
+        in_specs=[agents(m), agents(1), agents(1), agents(S), agents(m, S)],
+        out_specs=[agents(m), agents(m)],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad, m), z.dtype),
-            jax.ShapeDtypeStruct((n_pad, m), acc),
+            jax.ShapeDtypeStruct((m, n_pad), z.dtype),
+            jax.ShapeDtypeStruct((m, n_pad), acc),
         ],
         interpret=interpret,
-    )(z, mass, u, cdf, log_tables)
-    return z_new[:n], mu[:n]
+    )(z_t, mass_r, u_r, cdf_t, lt_t)
+    return z_new_t[:, :n].T, mu_t[:, :n].T

@@ -45,7 +45,6 @@ from repro.distributed.sharding import (
 from repro.distributed.trainer import (
     TrainConfig, make_train_step, _batch_spec_tree,
 )
-from repro.launch.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as M
 from repro.optim import AdamWConfig, adamw_init
@@ -164,7 +163,7 @@ def build_lowered(cfg: ArchConfig, shape: InputShape, mesh, agg: str,
             opt_struct = jax.eval_shape(
                 lambda p: adamw_init(p, opt_dtype), params_struct
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 jitted = jax.jit(
                     step_fn,
                     in_shardings=(
@@ -186,7 +185,7 @@ def build_lowered(cfg: ArchConfig, shape: InputShape, mesh, agg: str,
         step_fn = factory(pw_struct, tuple(batch))
         pspecs, ospecs, bspec = shard_fn(pw_struct, tuple(batch))
         key_struct = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 step_fn,
                 in_shardings=(
@@ -210,7 +209,7 @@ def build_lowered(cfg: ArchConfig, shape: InputShape, mesh, agg: str,
                 frames=batch.get("frames"),
             )
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 prefill_step,
                 in_shardings=(
@@ -242,7 +241,7 @@ def build_lowered(cfg: ArchConfig, shape: InputShape, mesh, agg: str,
 
     from repro.distributed.sharding import fit_spec
     tok_spec = fit_spec(P(batch_axes(mesh), None), (B, 1), mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             decode_fn,
             in_shardings=(
@@ -255,8 +254,6 @@ def build_lowered(cfg: ArchConfig, shape: InputShape, mesh, agg: str,
 
 def _extract_costs(compiled, n_dev):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     coll = parse_collectives(compiled.as_text(), n_dev)
     return {
         "flops": float(cost.get("flops", 0.0)),

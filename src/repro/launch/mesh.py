@@ -5,24 +5,28 @@ never touch jax device state (the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init;
 everything else must see the real device count).
 
-Meshes are built via :func:`repro.launch.compat.make_mesh`, which requests
-``AxisType.Auto`` axes on modern jax and silently drops the kwarg on jax
-0.4.x (where all mesh axes are implicitly auto) — see
-:mod:`repro.launch.compat` for the full compatibility story.
+Every mesh axis is ``AxisType.Auto``: GSPMD partitions whatever the
+programs do not shard_map by hand.
 """
 from __future__ import annotations
 
-import jax
-from jax.sharding import Mesh
+from typing import Sequence
 
-from repro.launch.compat import make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """v5e production mesh: 16x16 single pod, or 2 pods x 16 x 16."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int | None = None) -> Mesh:
@@ -30,4 +34,4 @@ def make_host_mesh(model_parallel: int | None = None) -> Mesh:
     n = jax.device_count()
     mp = model_parallel or 1
     assert n % mp == 0
-    return make_mesh((n // mp, mp), ("data", "model"))
+    return _auto_mesh((n // mp, mp), ("data", "model"))

@@ -21,7 +21,6 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     from repro.configs import get_config, reduced
-    from repro.launch.compat import set_mesh
     from repro.launch.mesh import make_host_mesh
     from repro.models import model as M
 
@@ -46,7 +45,7 @@ def main() -> None:
 
     cache_len = S + args.gen + (cfg.n_patches if cfg.family == "vlm" else 0) + 1
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits, cache = M.prefill(
             params, cfg, prompts, cache_len=cache_len, **kwargs
         )

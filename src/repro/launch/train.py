@@ -56,7 +56,6 @@ def main() -> None:
         TrainConfig, make_train_step, param_spread,
         replicate_for_workers, worker_opt_init,
     )
-    from repro.launch.compat import set_mesh
     from repro.launch.mesh import make_host_mesh
     from repro.models import model as M
     from repro.optim import AdamWConfig, adamw_init
@@ -90,7 +89,7 @@ def main() -> None:
 
     factory, _ = make_train_step(tc, mesh)
     robust = args.agg != "mean"
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if robust:
             params_w = replicate_for_workers(params, n_workers)
             opt_w = worker_opt_init(params_w)

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.launch import compat
 
 Params = dict[str, Any]
 
@@ -340,7 +339,7 @@ def moe_block(
     """Dispatches between the GSPMD one-shot dispatch and the shard_map
     expert-parallel implementation (EXPERIMENTS.md §Perf iteration 1)."""
     if cfg.moe_impl == "sharded":
-        mesh = compat.get_abstract_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         if mesh is not None and "model" in (mesh.axis_names or ()):
             return _moe_block_sharded(p, x, cfg, mesh)
     return _moe_block_gspmd(p, x, cfg)
@@ -485,13 +484,13 @@ def _moe_block_sharded(
             xt, router_w, w_gate, w_up, w_down, cfg, E, e_offset
         )
         y = jax.lax.psum(y, "model")
-        aux = jax.lax.psum(aux, "model") / compat.axis_size("model")
+        aux = jax.lax.psum(aux, "model") / jax.lax.axis_size("model")
         if baxes:
             aux = jax.lax.pmean(aux, baxes)
         return y.reshape(xb.shape), aux
 
     bspec = P(baxes if baxes else None, None, None)
-    out = compat.shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(bspec, P(None, None), P("model", None, None),

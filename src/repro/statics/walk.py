@@ -1,6 +1,6 @@
 """jaxpr IR walker — the traversal core every static check is built on.
 
-A traced :class:`jax.core.ClosedJaxpr` is a tree: equations at the top
+A traced :class:`jax.extend.core.ClosedJaxpr` is a tree: equations at the top
 level, with sub-jaxprs riding equation params (``scan``/``while`` carry
 their bodies under ``jaxpr``, ``cond`` under ``branches``, ``pjit``/
 ``custom_jvp_call``/``custom_vjp_call`` under ``jaxpr``/``call_jaxpr``,
@@ -34,6 +34,7 @@ from typing import Any, Callable, Iterator
 
 import jax
 import numpy as np
+from jax.extend import core as jcore
 
 __all__ = [
     "Value",
@@ -54,9 +55,9 @@ def subjaxprs(val) -> Iterator[Any]:
     either — the shapes ``scan``/``cond``/``pjit``/``custom_*``/
     ``pallas_call`` store their bodies in.
     """
-    if isinstance(val, jax.core.ClosedJaxpr):
+    if isinstance(val, jcore.ClosedJaxpr):
         yield val.jaxpr
-    elif isinstance(val, jax.core.Jaxpr):
+    elif isinstance(val, jcore.Jaxpr):
         yield val
     elif isinstance(val, (list, tuple)):
         for item in val:
@@ -70,7 +71,7 @@ def iter_eqns(jaxpr, path: tuple = ()) -> Iterator[tuple[tuple, Any]]:
     ``("scan", "cond")`` for an equation inside a branch inside the scan
     body — what a finding prints so the offending value is locatable.
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield path, eqn
@@ -158,7 +159,7 @@ def collect_literals(closed) -> list[tuple[tuple, Any]]:
     """
     out: list[tuple[tuple, Any]] = []
     jaxpr = closed
-    if isinstance(closed, jax.core.ClosedJaxpr):
+    if isinstance(closed, jcore.ClosedJaxpr):
         for c in closed.consts:
             arr = np.asarray(c)
             if arr.size <= _LITERAL_SCAN_CAP:
@@ -166,7 +167,7 @@ def collect_literals(closed) -> list[tuple[tuple, Any]]:
         jaxpr = closed.jaxpr
     for path, eqn in iter_eqns(jaxpr):
         for v in eqn.invars:
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jcore.Literal):
                 arr = np.asarray(v.val)
                 if arr.size <= _LITERAL_SCAN_CAP:
                     out.append((path + (eqn.primitive.name,), arr))
@@ -194,7 +195,7 @@ def symbolize(shape: tuple[int, ...], dims: dict[str, int]) -> tuple:
     return tuple(rev.get(int(d), int(d)) for d in shape)
 
 
-def trace(fn: Callable, *args, **kwargs) -> jax.core.ClosedJaxpr:
+def trace(fn: Callable, *args, **kwargs) -> jcore.ClosedJaxpr:
     """``jax.make_jaxpr`` with kwargs threaded through — the one tracing
     entry every check shares (abstract evaluation only; nothing runs)."""
     return jax.make_jaxpr(lambda *a: fn(*a, **kwargs))(*args)

@@ -472,7 +472,6 @@ class TestByzantineGrid:
             from repro.core.graphs import make_hierarchy
             from repro.core.signals import make_confused_model
             from repro.core.sweeps import run_byzantine_grid
-            from repro.launch import compat
 
             model = make_confused_model(N=15, m=3, truth=0, confusion=0.0,
                                         seed=0)
@@ -487,7 +486,8 @@ class TestByzantineGrid:
                 cfgs.append(ByzantineConfig(topo=topo, F=1, byz=(1,),
                                             gamma_period=4, attack=atk))
             r1 = run_byzantine_grid(model, cfgs, T=20, seeds=[0, 1])
-            mesh = compat.make_mesh((4,), ("data",))
+            mesh = jax.make_mesh((4,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             r2 = run_byzantine_grid(model, cfgs, T=20, seeds=[0, 1],
                                     mesh=mesh)
             same = bool((np.asarray(r1.decisions)

@@ -28,7 +28,6 @@ def _run_subprocess(body: str, devices: int = 8, timeout: int = 420) -> dict:
         import numpy as np
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.launch import compat
         {textwrap.indent(textwrap.dedent(body), '        ').strip()}
     """)
     env = dict(os.environ)
@@ -54,7 +53,8 @@ class TestAggregators:
         res = _run_subprocess("""
             from repro.distributed.aggregation import AGGREGATORS, AggregatorConfig
             from repro.kernels.trimmed_mean.ref import trimmed_mean_ref
-            mesh = compat.make_mesh((2, 4), ("pod", "data"))
+            mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             W, D = 8, 512
             rng = np.random.default_rng(0)
             g_all = jnp.asarray(rng.normal(size=(W, D)).astype(np.float32))
@@ -65,11 +65,11 @@ class TestAggregators:
                 def body(g, key):
                     out = fn({"g": g[0]}, cfg, "data", "pod", key)["g"]
                     return out[None]
-                sm = compat.shard_map(body, mesh=mesh,
-                                      in_specs=(P(("pod","data"), None), P()),
-                                      out_specs=P(("pod","data"), None),
-                                      axis_names=frozenset({"pod","data"}),
-                                      check_vma=False)
+                sm = jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P(("pod","data"), None), P()),
+                                   out_specs=P(("pod","data"), None),
+                                   axis_names=frozenset({"pod","data"}),
+                                   check_vma=False)
                 return np.asarray(jax.jit(sm)(g_all, jax.random.PRNGKey(0)))
 
             mean_err = float(np.abs(run("mean")[0] - np.asarray(g_all.mean(0))).max())
@@ -97,7 +97,8 @@ class TestAggregators:
     def test_hierarchical_trim_filters_byzantine_pod(self):
         res = _run_subprocess("""
             from repro.distributed.aggregation import AGGREGATORS, AggregatorConfig
-            mesh = compat.make_mesh((2, 4), ("pod", "data"))
+            mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
             rng = np.random.default_rng(1)
             D = 256
             honest = rng.normal(size=(8, D)).astype(np.float32)
@@ -107,11 +108,11 @@ class TestAggregators:
             fn = AGGREGATORS["hierarchical_trim"]
             def body(g, key):
                 return fn({"g": g[0]}, cfg, "data", "pod", key)["g"][None]
-            sm = compat.shard_map(body, mesh=mesh,
-                                  in_specs=(P(("pod","data"), None), P()),
-                                  out_specs=P(("pod","data"), None),
-                                  axis_names=frozenset({"pod","data"}),
-                                  check_vma=False)
+            sm = jax.shard_map(body, mesh=mesh,
+                               in_specs=(P(("pod","data"), None), P()),
+                               out_specs=P(("pod","data"), None),
+                               axis_names=frozenset({"pod","data"}),
+                               check_vma=False)
             out = np.asarray(jax.jit(sm)(jnp.asarray(g_all), jax.random.PRNGKey(0)))
             ok = bool((np.abs(out) <= np.abs(honest).max() + 1e-3).all())
             print(json.dumps(dict(bounded=ok, mx=float(np.abs(out).max()))))
@@ -133,7 +134,8 @@ class TestRobustTraining:
             from repro.optim import AdamWConfig
             from repro.data import SyntheticLMData
             import repro.models.model as M
-            mesh = compat.make_mesh((2,2,2), ("pod","data","model"))
+            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
             cfg = dataclasses.replace(reduced(get_config("paper_sim")),
                                       attn_impl="naive")
             params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -146,7 +148,7 @@ class TestRobustTraining:
             pw = replicate_for_workers(params, 4)
             ow = worker_opt_init(pw)
             losses = []
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = jax.jit(factory(pw))
                 for s in range(12):
                     pw, ow, loss = step(pw, ow, data.batch(s),
@@ -171,7 +173,8 @@ class TestRobustTraining:
             from repro.optim import AdamWConfig
             from repro.data import SyntheticLMData
             import repro.models.model as M
-            mesh = compat.make_mesh((2,2,2), ("pod","data","model"))
+            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
             cfg = dataclasses.replace(reduced(get_config("paper_sim")),
                                       attn_impl="naive")
             params = M.init_params(jax.random.PRNGKey(0), cfg)
@@ -184,7 +187,7 @@ class TestRobustTraining:
             pw = replicate_for_workers(params, 4)
             ow = worker_opt_init(pw)
             losses = []
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = jax.jit(factory(pw))
                 for s in range(10):
                     pw, ow, loss = step(pw, ow, data.batch(s),
@@ -213,13 +216,14 @@ class TestRobustTraining:
             data = SyntheticLMData(cfg.vocab, 32, 8, seed=0)
             results = {}
             for name, shape in [("dp_tp", (2, 4)), ("single", (1, 1))]:
-                mesh = compat.make_mesh(shape, ("data", "model"),
+                mesh = jax.make_mesh(shape, ("data", "model"),
+                    axis_types=(jax.sharding.AxisType.Auto,) * 2,
                     devices=jax.devices()[: shape[0]*shape[1]])
                 tc = TrainConfig(arch=cfg, opt=AdamWConfig(
                     lr=1e-3, warmup_steps=2, total_steps=20))
                 factory, _ = make_train_step(tc, mesh)
                 p, o = params, adamw_init(params)
-                with compat.set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     step = jax.jit(factory(p))
                     ls = []
                     for s in range(4):
@@ -241,8 +245,8 @@ class TestShardingRules:
         from repro.models import model as M
         from jax.sharding import PartitionSpec as P
 
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         for name, cfg in all_configs().items():
             r = reduced(cfg)
             struct = jax.eval_shape(

@@ -610,7 +610,6 @@ class TestHPSSweep:
             from repro.core.graphs import make_hierarchy
             from repro.core.hps import HPSConfig
             from repro.core.sweeps import run_hps_sweep
-            from repro.launch import compat
 
             topo = make_hierarchy([6, 6, 6], topology="complete", seed=0)
             w = np.random.default_rng(0).normal(size=(18, 3)).astype("float32")
@@ -618,7 +617,8 @@ class TestHPSSweep:
             kw = dict(drop_probs=[0.0, 0.4, 0.8], gammas=[4, 16],
                       seeds=[0, 1])
             r1 = run_hps_sweep(w, cfg, T=20, **kw)
-            mesh = compat.make_mesh((4,), ("data",))
+            mesh = jax.make_mesh((4,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             r2 = run_hps_sweep(w, cfg, T=20, mesh=mesh, **kw)
             same = bool((np.asarray(r1.gap) == np.asarray(r2.gap)).all())
             err = float(np.abs(np.asarray(r1.ratio)

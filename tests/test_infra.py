@@ -194,8 +194,8 @@ class TestDryRunHelpers:
         from repro.launch.dryrun import pick_n_micro
         from repro.launch.mesh import make_production_mesh
         import repro.launch.dryrun as DR
-        from repro.launch.compat import make_mesh
-        mesh = make_mesh((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
         class FakeMesh:
             shape = {"data": 16, "model": 16}
@@ -204,3 +204,35 @@ class TestDryRunHelpers:
             n = pick_n_micro(cfg, INPUT_SHAPES["train_4k"], FakeMesh())
             b_dev = 256 // 16
             assert b_dev % n == 0 and n >= 1
+
+
+class TestCompileCacheDir:
+    """The persistent compile cache is placeable from outside: it follows
+    ``JAX_COMPILATION_CACHE_DIR`` when set and sets nothing itself, and
+    otherwise sits at the fixed ``<checkout>/.jax-cache``."""
+
+    @pytest.mark.parametrize("env_dir", [None, "outside"])
+    def test_enable_picks_the_directory(self, env_dir, monkeypatch,
+                                        tmp_path):
+        from benchmarks import compile_cache
+
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        saved = {n: getattr(jax.config, n) for n in names}
+        # what jax itself read from the environment at import
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(compile_cache.REPO, ".jax-cache")
+        else:
+            want = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+        try:
+            got = compile_cache.enable()
+            assert got == want
+            expect_config = None if env_dir else want
+            assert jax.config.jax_compilation_cache_dir == expect_config
+        finally:
+            for n, v in saved.items():
+                jax.config.update(n, v)

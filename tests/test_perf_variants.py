@@ -107,16 +107,16 @@ def test_sharded_moe_matches_gspmd():
         import json, dataclasses
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import get_config, reduced
-        from repro.launch import compat
         from repro.models import layers as L
-        mesh = compat.make_mesh((2,2,2), ("pod","data","model"))
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                                 axis_types=(jax.sharding.AxisType.Auto,) * 3)
         cfg = reduced(get_config("olmoe_1b_7b"))
         p = L.init_moe(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                               dtype=jnp.float32)
         y_ref, _ = L._moe_block_gspmd(p, x, cfg)
         cfg_s = dataclasses.replace(cfg, moe_impl="sharded")
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_s, _ = jax.jit(lambda p, x: L.moe_block(p, x, cfg_s))(p, x)
         print(json.dumps({"err": float(jnp.abs(y_s - y_ref).max())}))
     """)
@@ -144,19 +144,19 @@ def test_sharded_trim_equals_plain_trim():
         from jax.sharding import PartitionSpec as P
         from repro.distributed.aggregation import AGGREGATORS, AggregatorConfig
         from repro.kernels.trimmed_mean.ref import trimmed_mean_ref
-        from repro.launch import compat
-        mesh = compat.make_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         g_all = jnp.asarray(rng.normal(size=(8, 1003)).astype(np.float32))
         cfg = AggregatorConfig(kind="trimmed_mean_sharded", F=2)
         fn = AGGREGATORS["trimmed_mean_sharded"]
         def body(g, key):
             return fn({"g": g[0]}, cfg, "data", "pod", key)["g"][None]
-        sm = compat.shard_map(body, mesh=mesh,
-                              in_specs=(P(("pod","data"), None), P()),
-                              out_specs=P(("pod","data"), None),
-                              axis_names=frozenset({"pod","data"}),
-                              check_vma=False)
+        sm = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(("pod","data"), None), P()),
+                           out_specs=P(("pod","data"), None),
+                           axis_names=frozenset({"pod","data"}),
+                           check_vma=False)
         out = np.asarray(jax.jit(sm)(g_all, jax.random.PRNGKey(0)))
         want = np.asarray(trimmed_mean_ref(g_all, 2))
         print(json.dumps({"err": float(np.abs(out - want).max())}))

@@ -242,7 +242,6 @@ class TestShardedSweep:
             from repro.core.graphs import (
                 random_strongly_connected, sort_by_dst, stack_edge_lists)
             from repro.core.sweeps import run_pushsum_sweep
-            from repro.launch import compat
 
             rng = np.random.default_rng(0)
             el, _, _ = sort_by_dst(stack_edge_lists(
@@ -250,7 +249,8 @@ class TestShardedSweep:
             w = rng.normal(size=(24, 2)).astype(np.float32)
             kw = dict(drop_probs=[0.0, 0.6], seeds=[0, 1, 2], B=4)
             r1 = run_pushsum_sweep(w, el, 80, **kw)
-            mesh = compat.make_mesh((4,), ("data",))
+            mesh = jax.make_mesh((4,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             r2 = run_pushsum_sweep(w, el, 80, mesh=mesh, **kw)  # K=12 -> pad 16
             err = float(np.abs(np.asarray(r2.err) - np.asarray(r1.err)).max())
             fin = float(np.abs(np.asarray(r2.final_ratio)

@@ -481,7 +481,6 @@ class TestSocialSweep:
             from repro.core.hps import HPSConfig
             from repro.core.signals import make_confused_model
             from repro.core.sweeps import run_social_sweep
-            from repro.launch import compat
 
             topo = make_hierarchy([6, 6, 6], topology="complete", seed=0)
             model = make_confused_model(N=18, m=3, truth=1, confusion=0.5,
@@ -490,7 +489,8 @@ class TestSocialSweep:
             kw = dict(drop_probs=[0.0, 0.4, 0.8], gammas=[4, 16],
                       seeds=[0, 1])
             r1 = run_social_sweep(model, cfg, T=20, **kw)
-            mesh = compat.make_mesh((4,), ("data",))
+            mesh = jax.make_mesh((4,), ("data",),
+                                 axis_types=(jax.sharding.AxisType.Auto,))
             r2 = run_social_sweep(model, cfg, T=20, mesh=mesh, **kw)
             same = bool((np.asarray(r1.log_ratio)
                          == np.asarray(r2.log_ratio)).all())
