@@ -517,38 +517,43 @@ def _scan_core(
 
     def body(carry, t):
         r, cum_llr = carry[0], carry[1]
+        # Each phase of the round runs under its own name scope (the same
+        # names as the social scan core's); scopes only label the HLO.
         if faults is not None:
-            fs, drop = step_faults_nbr(base_key, t, faults, carry[2],
-                                       engine=ENGINE_BYZANTINE)
-            live = fs.node_live
-            # a dropped/bursty slot or a dead endpoint silences the slot;
-            # the trim denominator (kept) shrinks with it, so gossip
-            # degrades to averaging over whoever actually delivered
-            rt_t = rt._replace(
-                nbr_valid=(rt.nbr_valid & ~drop
-                           & live[rt.nbr_idx] & live[:, None]))
+            with jax.named_scope("links"):
+                fs, drop = step_faults_nbr(base_key, t, faults, carry[2],
+                                           engine=ENGINE_BYZANTINE)
+                live = fs.node_live
+                # a dropped/bursty slot or a dead endpoint silences the
+                # slot; the trim denominator (kept) shrinks with it, so
+                # gossip degrades to averaging over whoever delivered
+                rt_t = rt._replace(
+                    nbr_valid=(rt.nbr_valid & ~drop
+                               & live[rt.nbr_idx] & live[:, None]))
         else:
             rt_t = rt
 
         # ---- innovation accumulator (cumulative LLR of all signals so far)
         # accumulate in the accum slot, carry in storage (every cast below
         # is a traced no-op under the default fp32 policy)
-        cum_new = (cum_llr.astype(ac_dt) + innovation(t)).astype(st_dt)
-        if faults is not None:
-            # dead agents observe no signals — the accumulator freezes
-            cum_new = jnp.where(live[sl], cum_new, cum_llr)
-        cum_llr = cum_new
+        with jax.named_scope("innovation"):
+            cum_new = (cum_llr.astype(ac_dt) + innovation(t)).astype(st_dt)
+            if faults is not None:
+                # dead agents observe no signals — the accumulator freezes
+                cum_new = jnp.where(live[sl], cum_new, cum_llr)
+            cum_llr = cum_new
 
         # ---- intra-C gossip with trimming (lines 6-9)
-        gk = jax.random.fold_in(base_key, stream_fold(t, STREAM_GOSSIP))
-        tsum, kept = gossip(gk, t, r, rt_t, F)
-        r_gossip = ((tsum + r.astype(ac_dt)) / (kept[sl] + 1.0)
-                    + cum_llr.astype(ac_dt))
-        r_new = jnp.where(rt.active[sl], r_gossip, r.astype(ac_dt))
-        r_new = r_new.astype(st_dt)
-        if faults is not None:
-            # dead agents neither gossip nor update — stale-state rejoin
-            r_new = jnp.where(live[sl], r_new, r)
+        with jax.named_scope("gossip"):
+            gk = jax.random.fold_in(base_key, stream_fold(t, STREAM_GOSSIP))
+            tsum, kept = gossip(gk, t, r, rt_t, F)
+            r_gossip = ((tsum + r.astype(ac_dt)) / (kept[sl] + 1.0)
+                        + cum_llr.astype(ac_dt))
+            r_new = jnp.where(rt.active[sl], r_gossip, r.astype(ac_dt))
+            r_new = r_new.astype(st_dt)
+            if faults is not None:
+                # dead agents neither gossip nor update — stale-state rejoin
+                r_new = jnp.where(live[sl], r_new, r)
 
         # ---- PS fusion every Γ (lines 10-22)
         def fuse(r_in):
@@ -559,24 +564,28 @@ def _scan_core(
                            else policy.accum,
                            live=None if faults is None else live)
 
-        is_fusion = (t + 1) % rt.gamma.astype(t.dtype) == 0
-        if faults is not None:
-            # PS crash: the whole fusion round is skipped — degrade to
-            # intra-network consensus instead of pooling through a dead PS
-            is_fusion = is_fusion & ps_alive(base_key, t, faults,
-                                             engine=ENGINE_BYZANTINE)
-        r_new = jax.lax.cond(is_fusion, fuse, lambda x: x, r_new)
+        with jax.named_scope("fusion"):
+            is_fusion = (t + 1) % rt.gamma.astype(t.dtype) == 0
+            if faults is not None:
+                # PS crash: the whole fusion round is skipped — degrade to
+                # intra-network consensus instead of pooling through a
+                # dead PS
+                is_fusion = is_fusion & ps_alive(base_key, t, faults,
+                                                 engine=ENGINE_BYZANTINE)
+            r_new = jax.lax.cond(is_fusion, fuse, lambda x: x, r_new)
 
-        # Byzantine agents' own state is meaningless; keep it at 0.
-        r_new = jnp.where(rt.byz_mask[sl], 0.0, r_new)
+            # Byzantine agents' own state is meaningless; keep it at 0.
+            r_new = jnp.where(rt.byz_mask[sl], 0.0, r_new)
 
-        dec = decide(r_new) if mode == "pairwise" else r_new.argmax(axis=-1)
-        if store == "trajectory":
-            ys = (r_new, dec)
-        elif store == "decisions":
-            ys = dec
-        else:
-            ys = None
+        with jax.named_scope("readout"):
+            dec = (decide(r_new) if mode == "pairwise"
+                   else r_new.argmax(axis=-1))
+            if store == "trajectory":
+                ys = (r_new, dec)
+            elif store == "decisions":
+                ys = dec
+            else:
+                ys = None
         out = (r_new, cum_llr) + (() if faults is None else (fs,))
         return out, ys
 

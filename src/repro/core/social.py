@@ -59,6 +59,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .asyncrony import (
     AsyncModel,
@@ -348,84 +349,96 @@ def _social_scan_core(
 
     def body(carry, t):
         state = carry[0]
-        if faults is not None:
-            fs = step_faults(mask_key, t, faults, carry[-1],
-                             engine=ENGINE_SOCIAL,
-                             graph_axis=graph_axis, n_shards=n_shards)
+        # Each phase of the round runs under its own name scope (the same
+        # names as the Byzantine scan core's); scopes only label the HLO.
+        # --- links: the fault plane and this round's operational edges ---
+        with jax.named_scope("links"):
+            if faults is not None:
+                fs = step_faults(mask_key, t, faults, carry[-1],
+                                 engine=ENGINE_SOCIAL,
+                                 graph_axis=graph_axis, n_shards=n_shards)
+                # drop uniform stays on the social link stream (degenerate
+                # model == step_edge_mask values draw-for-draw)
+                u_e = edge_uniforms(
+                    mask_key, social_stream_fold(t, STREAM_LINK), E,
+                    graph_axis=graph_axis, n_shards=n_shards)
+                mask = faulty_edge_mask(u_e, t, faults, fs, rt.src, rt.dst,
+                                        rt.drop_prob, rt.B)
+            elif graph_axis is not None:
+                mask = shard_edge_mask(
+                    mask_key, t, E, rt.drop_prob, rt.B,
+                    graph_axis=graph_axis, n_shards=n_shards,
+                    fold_t=social_stream_fold(t, STREAM_LINK),
+                )
+            else:
+                mask = step_edge_mask(
+                    mask_key, t, E, rt.drop_prob, rt.B,
+                    fold_t=social_stream_fold(t, STREAM_LINK),
+                )
+            if async_ is not None:
+                awake = wake_mask(mask_key, t, N, async_.wake_prob,
+                                  engine=ENGINE_SOCIAL)
         # --- consensus (lines 4-12) ---
-        if faults is not None:
-            # drop uniform stays on the social link stream (degenerate
-            # model == step_edge_mask values draw-for-draw)
-            u_e = edge_uniforms(
-                mask_key, social_stream_fold(t, STREAM_LINK), E,
-                graph_axis=graph_axis, n_shards=n_shards)
-            mask = faulty_edge_mask(u_e, t, faults, fs, rt.src, rt.dst,
-                                    rt.drop_prob, rt.B)
-        elif graph_axis is not None:
-            mask = shard_edge_mask(
-                mask_key, t, E, rt.drop_prob, rt.B,
-                graph_axis=graph_axis, n_shards=n_shards,
-                fold_t=social_stream_fold(t, STREAM_LINK),
-            )
-        else:
-            mask = step_edge_mask(
-                mask_key, t, E, rt.drop_prob, rt.B,
-                fold_t=social_stream_fold(t, STREAM_LINK),
-            )
-        if async_ is not None:
-            awake = wake_mask(mask_key, t, N, async_.wake_prob,
-                              engine=ENGINE_SOCIAL)
-            st, abuf = sparse_pushsum_step(
-                state, mask, rt.src, rt.dst, rt.valid, backend, share=share,
-                dst_sorted=dst_sorted, policy=policy,
-                faults=None if faults is None else fs,
-                awake=awake, abuf=carry[abuf_idx],
-                staleness=async_.staleness,
-            )
-        else:
-            st = sparse_pushsum_step(
-                state, mask, rt.src, rt.dst, rt.valid, backend, share=share,
-                graph_axis=graph_axis, dst_sorted=dst_sorted, policy=policy,
-                halo=halo, n_shards=n_shards,
-                faults=None if faults is None else fs,
-            )
+        with jax.named_scope("consensus"):
+            if async_ is not None:
+                st, abuf = sparse_pushsum_step(
+                    state, mask, rt.src, rt.dst, rt.valid, backend,
+                    share=share, dst_sorted=dst_sorted, policy=policy,
+                    faults=None if faults is None else fs,
+                    awake=awake, abuf=carry[abuf_idx],
+                    staleness=async_.staleness,
+                )
+            else:
+                st = sparse_pushsum_step(
+                    state, mask, rt.src, rt.dst, rt.valid, backend,
+                    share=share, graph_axis=graph_axis,
+                    dst_sorted=dst_sorted, policy=policy, halo=halo,
+                    n_shards=n_shards,
+                    faults=None if faults is None else fs,
+                )
         # --- innovation + belief (lines 13-16), one fused pass ---
-        sk = jax.random.fold_in(sig_key, social_stream_fold(t, STREAM_SIGNAL))
-        u = jax.random.uniform(sk, (N,))
-        z, mu = innovation_step(st.z, st.m, u, cdf, log_tables, backend,
-                                accum_dtype=accum_name)
-        if async_ is not None:
-            # asleep agents observe nothing: accumulator and belief stay
-            # at their frozen values until the next wake
-            z = freeze(awake, z, st.z)
-            mu = freeze(awake, mu, carry[1].astype(mu.dtype))
-        if faults is not None:
-            # dead agents observe nothing: the accumulator stays at its
-            # frozen post-consensus value and the belief stays stale
-            z = freeze(fs.node_live, z, st.z)
-            mu = freeze(fs.node_live, mu, carry[1].astype(mu.dtype))
+        with jax.named_scope("innovation"):
+            sk = jax.random.fold_in(sig_key,
+                                    social_stream_fold(t, STREAM_SIGNAL))
+            u = jax.random.uniform(sk, (N,))
+            z, mu = innovation_step(st.z, st.m, u, cdf, log_tables, backend,
+                                    accum_dtype=accum_name)
+            if async_ is not None:
+                # asleep agents observe nothing: accumulator and belief
+                # stay at their frozen values until the next wake
+                z = freeze(awake, z, st.z)
+                mu = freeze(awake, mu, carry[1].astype(mu.dtype))
+            if faults is not None:
+                # dead agents observe nothing: the accumulator stays at its
+                # frozen post-consensus value and the belief stays stale
+                z = freeze(fs.node_live, z, st.z)
+                mu = freeze(fs.node_live, mu, carry[1].astype(mu.dtype))
         # --- PS fusion every Γ (lines 17-22), applied post-innovation ---
-        z_f, m_f = hps_fusion(z, st.m, rt.rep_mask, M,
-                              accum_dtype=accum_name,
-                              live=None if faults is None else fs.node_live)
-        do_fusion = (t + 1) % rt.gamma == 0
-        if faults is not None:
-            # PS crash: skip the fusion round, degrade to local consensus
-            do_fusion = do_fusion & ps_alive(mask_key, t, faults,
-                                             engine=ENGINE_SOCIAL)
-        new = st._replace(
-            z=jnp.where(do_fusion, z_f, z),
-            m=jnp.where(do_fusion, m_f, st.m),
-        )
-        if store == "trajectory":
-            ys = mu
-        elif store == "log_ratio":
-            log_mu = jnp.log(jnp.maximum(mu, _MU_FLOOR))
-            lr = log_mu - log_mu[:, truth : truth + 1]
-            wrong = jnp.where(jnp.arange(m) == truth, -jnp.inf, lr)
-            ys = wrong.max()          # () worst wrong-hypothesis log ratio
-        else:
-            ys = None
+        with jax.named_scope("fusion"):
+            z_f, m_f = hps_fusion(z, st.m, rt.rep_mask, M,
+                                  accum_dtype=accum_name,
+                                  live=None if faults is None
+                                  else fs.node_live)
+            do_fusion = (t + 1) % rt.gamma == 0
+            if faults is not None:
+                # PS crash: skip the fusion round, degrade to local
+                # consensus
+                do_fusion = do_fusion & ps_alive(mask_key, t, faults,
+                                                 engine=ENGINE_SOCIAL)
+            new = st._replace(
+                z=jnp.where(do_fusion, z_f, z),
+                m=jnp.where(do_fusion, m_f, st.m),
+            )
+        with jax.named_scope("readout"):
+            if store == "trajectory":
+                ys = mu
+            elif store == "log_ratio":
+                log_mu = jnp.log(jnp.maximum(mu, _MU_FLOOR))
+                lr = log_mu - log_mu[:, truth : truth + 1]
+                wrong = jnp.where(jnp.arange(m) == truth, -jnp.inf, lr)
+                ys = wrong.max()      # () worst wrong-hypothesis log ratio
+            else:
+                ys = None
         out = (new,) + ((mu.astype(st_dt),) if carry_mu else ())
         if async_ is not None:
             out = out + (abuf,)
@@ -494,32 +507,41 @@ def run_social_runtime(
     degenerate ``plan.async_`` dispatches to the synchronous program
     (bit-identity by construction — see :mod:`repro.core.asyncrony`).
     """
-    plan = resolve_plan(
-        plan, _entry="run_social_runtime",
-        _supports=("backend", "store", "policy", "dst_sorted", "faults",
-                   "async_"),
-        **legacy)
-    store = "trajectory" if plan.store is None else plan.store
-    if store not in SOCIAL_STORES:
-        raise ValueError(f"store must be one of {SOCIAL_STORES}, got {store!r}")
-    async_ = None if is_degenerate_async(plan.async_) else plan.async_
-    truth_probs = model.tables[:, model.truth, :].astype(jnp.float32)
-    final, (beliefs, log_ratio) = _social_compiled(
-        jax.random.PRNGKey(seed),
-        jax.random.PRNGKey(seed if signal_seed is None else signal_seed),
-        rt,
-        model.log_tables().astype(jnp.float32),
-        jnp.cumsum(truth_probs, axis=-1),
-        truth=model.truth,
-        M=M,
-        T=T,
-        store=store,
-        backend=plan.backend,
-        policy=None if plan.policy is None else resolve_policy(plan.policy),
-        dst_sorted=plan.dst_sorted,
-        faults=plan.faults,
-        async_=async_,
-    )
+    # host spans: the argument preparation, then the dispatch of the
+    # compiled scan (which returns before the device finishes)
+    with TraceAnnotation("repro.social.prepare"):
+        plan = resolve_plan(
+            plan, _entry="run_social_runtime",
+            _supports=("backend", "store", "policy", "dst_sorted", "faults",
+                       "async_"),
+            **legacy)
+        store = "trajectory" if plan.store is None else plan.store
+        if store not in SOCIAL_STORES:
+            raise ValueError(
+                f"store must be one of {SOCIAL_STORES}, got {store!r}")
+        async_ = None if is_degenerate_async(plan.async_) else plan.async_
+        policy = None if plan.policy is None else resolve_policy(plan.policy)
+        truth_probs = model.tables[:, model.truth, :].astype(jnp.float32)
+        args = (
+            jax.random.PRNGKey(seed),
+            jax.random.PRNGKey(seed if signal_seed is None else signal_seed),
+            rt,
+            model.log_tables().astype(jnp.float32),
+            jnp.cumsum(truth_probs, axis=-1),
+        )
+    with TraceAnnotation("repro.social.dispatch"):
+        final, (beliefs, log_ratio) = _social_compiled(
+            *args,
+            truth=model.truth,
+            M=M,
+            T=T,
+            store=store,
+            backend=plan.backend,
+            policy=policy,
+            dst_sorted=plan.dst_sorted,
+            faults=plan.faults,
+            async_=async_,
+        )
     return SocialLearningResult(
         beliefs=beliefs, final_state=final, log_ratio=log_ratio
     )

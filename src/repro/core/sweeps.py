@@ -62,6 +62,7 @@ from typing import NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .attacks import Attack
@@ -1016,118 +1017,140 @@ def run_byzantine_grid(
     """
     from repro.kernels.byz_trim import resolve_backend
 
-    plan = resolve_plan(
-        plan, _entry="run_byzantine_grid",
-        _supports=("backend", "store", "mesh", "data_axis", "policy",
-                   "faults"),
-        **legacy)
-    backend, mesh, data_axis = plan.backend, plan.mesh, plan.data_axis
-    policy, faults = plan.policy, plan.faults
-    store = "decisions" if plan.store is None else plan.store
+    # host spans: ``prepare`` (with ``fingerprint``, ``build`` on a cache
+    # miss only, and ``batch`` inside it), then ``dispatch`` of the
+    # compiled grid and ``unpack`` of its result
+    with TraceAnnotation("repro.byz_grid.prepare"):
+        plan = resolve_plan(
+            plan, _entry="run_byzantine_grid",
+            _supports=("backend", "store", "mesh", "data_axis", "policy",
+                       "faults"),
+            **legacy)
+        backend, mesh, data_axis = plan.backend, plan.mesh, plan.data_axis
+        policy, faults = plan.policy, plan.faults
+        store = "decisions" if plan.store is None else plan.store
 
-    cfgs = list(cfgs)
-    if not cfgs:
-        raise ValueError("need at least one config")
-    atk = attack if attack is not None else cfgs[0].attack
-    N, M = cfgs[0].topo.N, cfgs[0].topo.M
-    if any(c.topo.N != N or c.topo.M != M for c in cfgs) or model.N != N:
-        raise ValueError("grid configs (and the model) must share (N, M)")
+        cfgs = list(cfgs)
+        if not cfgs:
+            raise ValueError("need at least one config")
+        atk = attack if attack is not None else cfgs[0].attack
+        N, M = cfgs[0].topo.N, cfgs[0].topo.M
+        if any(c.topo.N != N or c.topo.M != M for c in cfgs) \
+                or model.N != N:
+            raise ValueError("grid configs (and the model) must share (N, M)")
 
-    rt_key = _cfgs_fingerprint(model, cfgs, atk)
-    hit = _BYZ_RUNTIME_CACHE.get(rt_key)
-    if hit is None:
-        runtimes = []
-        for c in cfgs:
-            rt, extra_reps, _, _ = make_byzantine_runtime(
-                model, dataclasses.replace(c, attack=atk)
-            )
-            if extra_reps is not None:
-                raise ValueError(
-                    "grid configs must satisfy M >= 2F+1 (the all-networks "
-                    f"representative rule); config with F={c.F}, "
-                    f"M={c.topo.M} needs the static extra-reps branch"
-                )
-            runtimes.append(rt)
-        deg_max = max(int(rt.nbr_idx.shape[1]) for rt in runtimes)
+        with TraceAnnotation("repro.byz_grid.fingerprint"):
+            rt_key = _cfgs_fingerprint(model, cfgs, atk)
+        hit = _BYZ_RUNTIME_CACHE.get(rt_key)
+        if hit is None:
+            with TraceAnnotation("repro.byz_grid.build"):
+                hit = _BYZ_RUNTIME_CACHE[rt_key] = _stack_byz_runtimes(
+                    model, cfgs, atk)
+        stacked = hit
+        Fs = np.asarray([c.F for c in cfgs], np.int32)
+        # a uniform F stays a static Python int (Pallas-trim eligible);
+        # heterogeneous F is traced per scenario, which needs the sort
+        # lowering. backend is normalized to the effective lowering so the
+        # compiled-cache key names the traced program on every platform.
+        static_F = int(Fs[0]) if bool((Fs == Fs[0]).all()) else None
+        backend = resolve_backend(backend)
+        if static_F is None and backend == "pallas":
+            backend = "xla"
 
-        def pad_rt(rt: ByzRuntime) -> ByzRuntime:
-            pad = deg_max - rt.nbr_idx.shape[1]
-            return rt._replace(
-                nbr_idx=jnp.pad(rt.nbr_idx, ((0, 0), (0, pad))),
-                nbr_valid=jnp.pad(rt.nbr_valid, ((0, 0), (0, pad))),
-            )
-
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *[pad_rt(rt) for rt in runtimes]
+        seeds_np = np.atleast_1d(np.asarray(seeds, np.uint32))
+        gi, sd = np.meshgrid(
+            np.arange(len(cfgs), dtype=np.int32), seeds_np, indexing="ij"
         )
-        hit = _BYZ_RUNTIME_CACHE[rt_key] = stacked
-    stacked = hit
-    Fs = np.asarray([c.F for c in cfgs], np.int32)
-    # a uniform F stays a static Python int (Pallas-trim eligible);
-    # heterogeneous F is traced per scenario, which needs the sort lowering.
-    # backend is normalized to the effective lowering so the compiled-cache
-    # key names the traced program on every platform.
-    static_F = int(Fs[0]) if bool((Fs == Fs[0]).all()) else None
-    backend = resolve_backend(backend)
-    if static_F is None and backend == "pallas":
-        backend = "xla"
-
-    seeds_np = np.atleast_1d(np.asarray(seeds, np.uint32))
-    gi, sd = np.meshgrid(
-        np.arange(len(cfgs), dtype=np.int32), seeds_np, indexing="ij"
-    )
-    gi, sd = gi.ravel(), sd.ravel()
-    K = gi.shape[0]
-    if mesh is not None:
-        pad = (-K) % int(mesh.shape[data_axis])
-        if pad:
-            fill = np.full(pad, K - 1)
-            gi = np.concatenate([gi, gi[fill]])
-            sd = np.concatenate([sd, sd[fill]])
-
-    pol = None if policy is None else resolve_policy(policy)
-    cache_key = _byz_grid_key(model, cfgs, T, atk, mode, backend, store,
-                              mesh, data_axis, pol, faults)
-    fn = _BYZ_GRID_COMPILED.get(cache_key)
-    if fn is None:
-        single = functools.partial(
-            _scan_core,
-            faults=faults,
-            gossip=functools.partial(
-                _sparse_gossip, attack=atk, mode=mode, backend=backend,
-                accum_dtype=None if pol is None else pol.accum,
-            ),
-            log_tables=model.log_tables().astype(jnp.float32),
-            truth_probs=model.tables[:, model.truth, :].astype(jnp.float32),
-            T=T,
-            mode=mode,
-            attack=atk,
-            store=store,
-            static_F=static_F,
-            extra_reps=None,
-            n_reps=M,
-            policy=pol,
-        )
-        batched = jax.vmap(single)
+        gi, sd = gi.ravel(), sd.ravel()
+        K = gi.shape[0]
         if mesh is not None:
-            spec = P(data_axis)
-            batched = jax.shard_map(
-                batched,
-                mesh=mesh,
-                in_specs=(spec, ByzRuntime(*([spec] * len(ByzRuntime._fields)))),
-                out_specs=ByzantineResult(r=spec, decisions=spec),
-                check_vma=False,
-            )
-        fn = _BYZ_GRID_COMPILED[cache_key] = jax.jit(batched)
+            pad = (-K) % int(mesh.shape[data_axis])
+            if pad:
+                fill = np.full(pad, K - 1)
+                gi = np.concatenate([gi, gi[fill]])
+                sd = np.concatenate([sd, sd[fill]])
 
-    keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(sd))
-    rt_batch = jax.tree_util.tree_map(lambda x: x[jnp.asarray(gi)], stacked)
-    res = fn(keys, rt_batch)
-    return ByzantineGridResult(
-        r=res.r[:K], decisions=res.decisions[:K],
-        cfg=jnp.asarray(gi[:K]), F=jnp.asarray(Fs[gi[:K]]),
-        seed=jnp.asarray(sd[:K]),
-        fault=None if faults is None else jnp.zeros(K, jnp.int32),
+        pol = None if policy is None else resolve_policy(policy)
+        with TraceAnnotation("repro.byz_grid.fingerprint"):
+            cache_key = _byz_grid_key(model, cfgs, T, atk, mode, backend,
+                                      store, mesh, data_axis, pol, faults)
+        fn = _BYZ_GRID_COMPILED.get(cache_key)
+        if fn is None:
+            with TraceAnnotation("repro.byz_grid.build"):
+                single = functools.partial(
+                    _scan_core,
+                    faults=faults,
+                    gossip=functools.partial(
+                        _sparse_gossip, attack=atk, mode=mode,
+                        backend=backend,
+                        accum_dtype=None if pol is None else pol.accum,
+                    ),
+                    log_tables=model.log_tables().astype(jnp.float32),
+                    truth_probs=model.tables[:, model.truth, :].astype(
+                        jnp.float32),
+                    T=T,
+                    mode=mode,
+                    attack=atk,
+                    store=store,
+                    static_F=static_F,
+                    extra_reps=None,
+                    n_reps=M,
+                    policy=pol,
+                )
+                batched = jax.vmap(single)
+                if mesh is not None:
+                    spec = P(data_axis)
+                    batched = jax.shard_map(
+                        batched,
+                        mesh=mesh,
+                        in_specs=(spec, ByzRuntime(
+                            *([spec] * len(ByzRuntime._fields)))),
+                        out_specs=ByzantineResult(r=spec, decisions=spec),
+                        check_vma=False,
+                    )
+                fn = _BYZ_GRID_COMPILED[cache_key] = jax.jit(batched)
+
+        with TraceAnnotation("repro.byz_grid.batch"):
+            keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray(sd))
+            rt_batch = jax.tree_util.tree_map(lambda x: x[jnp.asarray(gi)],
+                                              stacked)
+    with TraceAnnotation("repro.byz_grid.dispatch"):
+        res = fn(keys, rt_batch)
+    with TraceAnnotation("repro.byz_grid.unpack"):
+        return ByzantineGridResult(
+            r=res.r[:K], decisions=res.decisions[:K],
+            cfg=jnp.asarray(gi[:K]), F=jnp.asarray(Fs[gi[:K]]),
+            seed=jnp.asarray(sd[:K]),
+            fault=None if faults is None else jnp.zeros(K, jnp.int32),
+        )
+
+
+def _stack_byz_runtimes(model, cfgs, atk) -> ByzRuntime:
+    """Every config's :class:`ByzRuntime`, neighbour lists padded to the
+    common deg_max, stacked leaf-wise onto a scenario axis."""
+    runtimes = []
+    for c in cfgs:
+        rt, extra_reps, _, _ = make_byzantine_runtime(
+            model, dataclasses.replace(c, attack=atk)
+        )
+        if extra_reps is not None:
+            raise ValueError(
+                "grid configs must satisfy M >= 2F+1 (the all-networks "
+                f"representative rule); config with F={c.F}, "
+                f"M={c.topo.M} needs the static extra-reps branch"
+            )
+        runtimes.append(rt)
+    deg_max = max(int(rt.nbr_idx.shape[1]) for rt in runtimes)
+
+    def pad_rt(rt: ByzRuntime) -> ByzRuntime:
+        pad = deg_max - rt.nbr_idx.shape[1]
+        return rt._replace(
+            nbr_idx=jnp.pad(rt.nbr_idx, ((0, 0), (0, pad))),
+            nbr_valid=jnp.pad(rt.nbr_valid, ((0, 0), (0, pad))),
+        )
+
+    return jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), *[pad_rt(rt) for rt in runtimes]
     )
 
 
