@@ -41,6 +41,7 @@ __all__ = [
     "stack_edge_lists",
     "edge_masks",
     "sort_by_dst",
+    "run_ends",
     "EdgeShards",
     "partition_edge_list",
     "block_complete_edge_list",
@@ -540,6 +541,21 @@ def _dst_offsets(sorted_dst: np.ndarray, n: int) -> np.ndarray:
     return np.stack([
         np.searchsorted(row, grid, side="left") for row in sorted_dst
     ]).astype(np.int32)
+
+
+def run_ends(dst: np.ndarray, n: int) -> np.ndarray | None:
+    """(N,) int32 index of each receiver's last in-edge in a dst-sorted
+    edge index, ``-1`` for a receiver with none; ``None`` when ``dst`` is
+    not sorted (nothing is sorted here: sorting would re-index the edges'
+    per-round draws). The run-end gather finish of
+    :mod:`repro.kernels.pushsum_edge` reads these lanes."""
+    dst = np.asarray(dst)
+    if np.any(dst[1:] < dst[:-1]):
+        return None
+    grid = np.arange(n)
+    lo = np.searchsorted(dst, grid, side="left")
+    hi = np.searchsorted(dst, grid, side="right")
+    return np.where(hi > lo, hi - 1, -1).astype(np.int32)
 
 
 @dataclasses.dataclass(frozen=True)

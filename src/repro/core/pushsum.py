@@ -264,6 +264,7 @@ def sparse_pushsum_step(
     awake: jnp.ndarray | None = None,
     abuf: AsyncBuffer | None = None,
     staleness: jnp.ndarray | None = None,
+    seg_end: jnp.ndarray | None = None,
 ) -> SparsePushSumState | tuple[SparsePushSumState, AsyncBuffer]:
     """One fast-robust-push-sum iteration on edge-list state.
 
@@ -301,6 +302,12 @@ def sparse_pushsum_step(
     ``dst_sorted=True`` asserts the edge index is dst-sorted (the
     partitioner's layout, or :func:`graphs.sort_by_dst` output) and lets
     the XLA lowering's ``segment_sum`` skip its internal sort.
+
+    ``seg_end`` ((N,) int32, :func:`graphs.run_ends` of a dst-sorted index)
+    lets the Pallas lowering finish each receiver's sum with a gather at
+    its run end instead of the ``segment_sum`` scatter (see
+    :mod:`repro.kernels.pushsum_edge`). Single-device only; the XLA and
+    async lowerings do not read it, and ``None`` keeps the scatter.
 
     **Precision policy** (``policy=``, see :mod:`repro.core.precision`):
     persistent state stays in the storage dtype, elementwise staging runs
@@ -355,6 +362,9 @@ def sparse_pushsum_step(
 
     if halo not in HALO_VARIANTS:
         raise ValueError(f"halo={halo!r} not in {HALO_VARIANTS}")
+    if seg_end is not None and graph_axis is not None:
+        raise ValueError("seg_end indexes the whole edge list; an edge "
+                         "shard has run ends of its own")
     pol = None if policy is None else resolve_policy(policy)
     z, m, sigma, sigma_m, rho, rho_m = state
     n = z.shape[0]
@@ -426,7 +436,8 @@ def sparse_pushsum_step(
         rho_cat = jnp.concatenate([rho, rho_m[:, None]], axis=1)
         rho_cat_new, recv_cat = edge_scatter(
             sigma_cat, rho_cat, live, src, dst, backend="pallas",
-            indices_sorted=dst_sorted, accum_dtype=accum_name,
+            seg_end=seg_end, indices_sorted=dst_sorted,
+            accum_dtype=accum_name,
         )
         rho_new, rho_m_new = rho_cat_new[:, :-1], rho_cat_new[:, -1]
         recv, recv_m = recv_cat[:, :-1], recv_cat[:, -1]

@@ -77,7 +77,7 @@ from .faults import (
     ps_alive,
     step_faults,
 )
-from .graphs import EdgeList
+from .graphs import EdgeList, run_ends
 from .hps import HPSConfig, hps_fusion
 from .plan import ExecutionPlan, resolve_plan
 from .precision import Policy, resolve_policy
@@ -104,6 +104,7 @@ __all__ = [
     "kl_dual_averaging_update",
     "make_social_runtime",
     "social_runtime_from_edge_list",
+    "stack_runtimes",
     "run_social_learning",
     "run_social_runtime",
     "theorem2_rate",
@@ -159,7 +160,8 @@ class SocialRuntime(NamedTuple):
     per-scenario: the fusion schedule ``(t + 1) % gamma == 0`` and the
     B-window forced delivery are computed in-scan from the traced values,
     keeping ONE compiled program for the whole (drop x Gamma x topology)
-    grid.
+    grid. ``seg_end`` is set only for a dst-sorted edge index; a batch
+    stacks it only if every scenario has it (:func:`stack_runtimes`).
     """
 
     src: jnp.ndarray        # (E,) int32 sender per edge (dst-sorted layout)
@@ -169,6 +171,9 @@ class SocialRuntime(NamedTuple):
     drop_prob: jnp.ndarray  # () f32 per-link packet-drop probability
     gamma: jnp.ndarray      # () i32 PS fusion period
     B: jnp.ndarray          # () i32 link-reliability window
+    # (N,) i32 each receiver's last in-edge (-1: none) when the edge index
+    # is dst-sorted, else None: the consensus step's run-end gather finish
+    seg_end: jnp.ndarray | None = None
 
 
 def kl_dual_averaging_update(z: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:
@@ -198,7 +203,9 @@ def social_runtime_from_edge_list(
     backend accepts any order. ``e_max`` pads the edge axis (inert
     ``valid=False`` edges with ``dst = N - 1``, which keeps a sorted layout
     sorted) so scenario batches over different topologies can share one
-    shape.
+    shape. A dst-sorted (padded) index also gets its receivers' run ends
+    (``seg_end``, :func:`repro.core.graphs.run_ends`), which let the Pallas
+    consensus step finish with a gather; an unsorted one is left as it is.
     """
     if el.is_batched:
         raise ValueError("pass one topology draw; batching happens leaf-wise")
@@ -210,6 +217,7 @@ def social_runtime_from_edge_list(
         src = np.concatenate([src, np.zeros(pad, np.int32)])
         dst = np.concatenate([dst, np.full(pad, el.n - 1, np.int32)])
         valid = np.concatenate([valid, np.zeros(pad, bool)])
+    seg_end = run_ends(dst, el.n)
     return SocialRuntime(
         src=jnp.asarray(src, jnp.int32),
         dst=jnp.asarray(dst, jnp.int32),
@@ -218,7 +226,17 @@ def social_runtime_from_edge_list(
         drop_prob=jnp.asarray(drop_prob, jnp.float32),
         gamma=jnp.asarray(gamma_period, jnp.int32),
         B=jnp.asarray(B, jnp.int32),
+        seg_end=None if seg_end is None else jnp.asarray(seg_end, jnp.int32),
     )
+
+
+def stack_runtimes(runtimes) -> SocialRuntime:
+    """Stack compatible runtimes leaf-wise onto a leading scenario axis.
+    Unless every runtime has its run ends, none keeps them, so the batch
+    runs one program."""
+    if any(rt.seg_end is None for rt in runtimes):
+        runtimes = [rt._replace(seg_end=None) for rt in runtimes]
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *runtimes)
 
 
 def make_social_runtime(cfg: HPSConfig, e_max: int | None = None) -> SocialRuntime:
@@ -295,6 +313,9 @@ def _social_scan_core(
     softmax run in the accum dtype. ``dst_sorted=True`` asserts the
     runtime's edge index is dst-sorted (true for everything built from
     ``HPSConfig.edge_index()``; user-supplied runtimes default to False).
+    A runtime with run ends (``rt.seg_end``) finishes the Pallas consensus
+    step with the run-end gather on a single device without async mode;
+    every other path does not read them.
     All of these kwargs are trace statics — except ``faults``, a TRACED
     :class:`repro.core.faults.FaultModel` pytree riding the vmap scenario
     axis: bursty Gilbert-Elliott links, churn (dead agents neither gossip
@@ -395,6 +416,8 @@ def _social_scan_core(
                     dst_sorted=dst_sorted, policy=policy, halo=halo,
                     n_shards=n_shards,
                     faults=None if faults is None else fs,
+                    # run ends index the whole edge list, not a shard's
+                    seg_end=rt.seg_end if graph_axis is None else None,
                 )
         # --- innovation + belief (lines 13-16), one fused pass ---
         with jax.named_scope("innovation"):

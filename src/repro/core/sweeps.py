@@ -109,7 +109,13 @@ from .hps import (
     make_hps_runtime,
 )
 from .signals import SignalModel
-from .social import SOCIAL_STORES, SocialRuntime, _social_scan_core, make_social_runtime
+from .social import (
+    SOCIAL_STORES,
+    SocialRuntime,
+    _social_scan_core,
+    make_social_runtime,
+    stack_runtimes,
+)
 from repro.statics.contracts import contract as statics_contract
 from repro.statics.retrace import register_cache as register_statics_cache
 
@@ -1370,9 +1376,7 @@ def run_social_grid(
     if stacked is None:
         e_max = max(int(np.count_nonzero(c.topo.adj)) for c in cfgs)
         runtimes = [make_social_runtime(c, e_max=e_max) for c in cfgs]
-        stacked = _SOCIAL_RUNTIME_CACHE[rt_key] = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *runtimes
-        )
+        stacked = _SOCIAL_RUNTIME_CACHE[rt_key] = stack_runtimes(runtimes)
 
     seeds_np = np.atleast_1d(np.asarray(seeds, np.uint32))
     gi, sd = np.meshgrid(

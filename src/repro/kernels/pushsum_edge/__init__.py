@@ -10,7 +10,9 @@ round. With the edge index pre-sorted by ``dst``
 (:func:`repro.core.graphs.sort_by_dst`) each receiver's increments form
 one contiguous run, and the Pallas kernel in :mod:`.pushsum_edge` streams
 E once to latch and reduce every run to one partial sum; the gather before
-it and the per-receiver finish after it stay in XLA. :mod:`.ref` is the
+it and the per-receiver finish after it (a ``segment_sum``, or a gather at
+each receiver's run end when the caller supplies the run ends) stay in
+XLA. :mod:`.ref` is the
 always-available XLA fallback and the equivalence oracle; :mod:`.ops`
 hosts the ``backend="auto"|"xla"|"pallas"`` dispatch used by
 :func:`repro.core.pushsum.sparse_pushsum_step`.

@@ -33,6 +33,7 @@ def edge_scatter(
     dst: jnp.ndarray,     # (E,) int32
     backend: str = "auto",
     *,
+    seg_end: jnp.ndarray | None = None,
     block_e: int = 4096,
     interpret: bool | None = None,
     indices_sorted: bool = False,
@@ -46,12 +47,17 @@ def edge_scatter(
     ``accum_dtype`` names the dtype of the ``recv`` reduction (the
     precision policy's accum slot — see :mod:`repro.core.precision`);
     ``None`` keeps the input dtype.
+    ``seg_end`` (each receiver's last in-edge of a dst-sorted index, ``-1``
+    for none) lets the Pallas lowering finish with a gather at the run
+    ends instead of the ``segment_sum`` scatter; the XLA lowering, the
+    oracle, does not read it.
     """
     if resolve_backend(backend) == "xla":
         return edge_scatter_ref(sigma, rho, live, src, dst,
                                 indices_sorted=indices_sorted,
                                 accum_dtype=accum_dtype)
     return edge_scatter_pallas(
-        sigma, rho, live, src, dst, block_e=block_e, interpret=interpret,
-        accum_dtype=accum_dtype, indices_sorted=indices_sorted,
+        sigma, rho, live, src, dst, seg_end=seg_end, block_e=block_e,
+        interpret=interpret, accum_dtype=accum_dtype,
+        indices_sorted=indices_sorted,
     )

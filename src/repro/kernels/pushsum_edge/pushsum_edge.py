@@ -10,10 +10,12 @@ Design (see /opt/skills/guides/pallas_guide.md)
 * Data-dependent addressing stays in XLA, the streaming arithmetic in the
   kernel. The row gather ``sigma[src]`` runs as an XLA gather before the
   call and the kernel receives the gathered rows as a blocked operand;
-  the per-receiver finish is a sorted ``segment_sum`` over the kernel's
-  run-end partials after it. Mosaic lowers neither an in-kernel row gather
-  nor a data-dependent scatter, and nothing node-indexed has to be VMEM
-  resident: every operand is a block of the edge axis.
+  the per-receiver finish after it is either a ``segment_sum`` over the
+  kernel's run-end partials or, given each receiver's run end
+  (``seg_end``), an N-row gather of the kernel's whole-run sums. Mosaic
+  lowers neither an in-kernel row gather nor a data-dependent scatter, and
+  nothing node-indexed has to be VMEM resident: every operand is a block
+  of the edge axis.
 * Layout: edges on the 128-wide lane axis, the feature axis D = d+1 on
   sublanes — ``(D, block_e)`` tiles, so a small D costs sublane padding
   (5 -> 8) instead of lane padding (5 -> 128). ``live`` and ``dst`` ride as
@@ -33,6 +35,14 @@ Design (see /opt/skills/guides/pallas_guide.md)
   ``segment_sum`` finish adds fragments of one receiver together. A run
   that spans a block boundary ends at the block's last lane and reopens
   at the next block's first lane in the same way.
+* Run-end gather (``seg_end`` given, dst-sorted index only): the grid runs
+  in order (``"arbitrary"``; a v5e has one TensorCore, so nothing runs in
+  parallel anyway) and two VMEM scratch buffers carry the sum and the
+  receiver of the run open at a block's last lane into the next block,
+  whose first run adds it. The last lane of every receiver's whole run
+  then holds its full increment sum, and the finish is a gather of N
+  lanes in place of a scatter of E. The carry adds a run's earlier
+  partial sum to its later ones, so every sum stays segment-local.
 * Padding edges (to a multiple of ``block_e``) carry ``live = 0`` and the
   out-of-range receiver ``N``, so the last real run still ends on a real
   lane; the pad lanes are sliced off before the finish.
@@ -76,7 +86,8 @@ def _segmented_cumsum(v, is_first):
     return v
 
 
-def _kernel(sent_ref, rho_ref, live_ref, dst_ref, rho_out_ref, upd_ref):
+def _kernel(sent_ref, rho_ref, live_ref, dst_ref, rho_out_ref, upd_ref,
+            *carry_refs):
     rho = rho_ref[...]                           # (D, BE)
     live = live_ref[...] != 0                    # (1, BE)
 
@@ -95,6 +106,22 @@ def _kernel(sent_ref, rho_ref, live_ref, dst_ref, rho_out_ref, upd_ref):
     is_first = (lane == 0) | (dst != pltpu.roll(dst, 1, 1))
     is_end = (lane == be - 1) | (dst != pltpu.roll(dst, be - 1, 1))
     seg = _segmented_cumsum(delta, is_first.astype(jnp.int32))
+    if carry_refs:
+        # --- runs carried across blocks (dst-sorted, sequential grid) ---
+        # the run open at the previous block's last lane continues into
+        # this block's first run iff its receiver is the same; in a sorted
+        # block those are exactly the lanes whose dst equals the carried one
+        carry_ref, carry_dst_ref = carry_refs
+
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            carry_ref[...] = jnp.zeros_like(carry_ref)
+            carry_dst_ref[...] = jnp.full_like(carry_dst_ref, -1)
+
+        seg = seg + jnp.where(dst == carry_dst_ref[...], carry_ref[...], 0.0)
+        carry_ref[...] = jnp.sum(jnp.where(lane == be - 1, seg, 0.0), axis=1,
+                                 keepdims=True)
+        carry_dst_ref[...] = jnp.max(dst, axis=1, keepdims=True)
     upd_ref[...] = jnp.where(is_end, seg, 0.0).astype(acc)
 
 
@@ -109,6 +136,7 @@ def edge_scatter_pallas(
     src: jnp.ndarray,     # (E,) int32
     dst: jnp.ndarray,     # (E,) int32, pre-sorted ascending for the fast path
     *,
+    seg_end: jnp.ndarray | None = None,  # (N,) int32 last in-edge, or -1
     block_e: int = 4096,
     interpret: bool | None = None,
     accum_dtype: str | None = None,
@@ -123,6 +151,14 @@ def edge_scatter_pallas(
     accum slot) — ``None`` keeps the input dtype, the pre-policy program.
     ``indices_sorted=True`` promises a dst-sorted index to the
     ``segment_sum`` finish.
+
+    ``seg_end`` (a dst-sorted index only; see
+    :func:`repro.core.graphs.run_ends`) holds each receiver's last in-edge,
+    ``-1`` where it has none. The grid then runs in order and carries the
+    run open at each block's end into the next block, so the last lane of
+    each receiver's whole run holds its full increment sum, and the finish
+    gathers those N lanes instead of scattering all E partials.
+    ``seg_end=None`` is the scatter finish.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -144,6 +180,14 @@ def edge_scatter_pallas(
 
     edge_block = pl.BlockSpec((D, block_e), lambda i: (0, i))
     row_block = pl.BlockSpec((1, block_e), lambda i: (0, i))
+    carry = {}
+    if seg_end is not None:
+        carry = dict(
+            scratch_shapes=[pltpu.VMEM((D, 1), acc),
+                            pltpu.VMEM((1, 1), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+        )
     rho_new_t, upd_t = pl.pallas_call(
         _kernel,
         grid=(Ep // block_e,),
@@ -154,8 +198,14 @@ def edge_scatter_pallas(
             jax.ShapeDtypeStruct((D, Ep), acc),
         ],
         interpret=interpret,
+        **carry,
     )(sent_t, rho_t, live_r, dst_r)
-    recv = jax.ops.segment_sum(
-        upd_t[:, :E].T, dst, num_segments=n, indices_are_sorted=indices_sorted
-    )
+    if seg_end is None:
+        recv = jax.ops.segment_sum(
+            upd_t[:, :E].T, dst, num_segments=n,
+            indices_are_sorted=indices_sorted)
+    else:
+        with jax.named_scope("run_end_gather"):
+            recv = jnp.where((seg_end >= 0)[:, None],
+                             upd_t[:, jnp.maximum(seg_end, 0)].T, 0.0)
     return rho_new_t[:, :E].T, recv

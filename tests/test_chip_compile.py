@@ -1,4 +1,5 @@
-"""The engine kernels compile for a TPU v5e at the sizes chip_smoke.py runs.
+"""The engine kernels compile for a TPU v5e at the sizes chip_smoke.py runs
+and, for the push-sum edge step's run-end gather, at the social cells' size.
 
 Interpret mode (what every other kernel test runs on CPU) accepts programs
 Mosaic refuses — an in-kernel row gather, a block that overflows scoped
@@ -55,6 +56,22 @@ def test_pushsum_edge_compiles_at_N131072(one_chip):
         one_chip, ((n, d), jnp.float32), ((e, d), jnp.float32),
         ((e,), jnp.bool_), ((e,), jnp.int32), ((e,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_pushsum_edge_run_end_gather_compiles_at_N1048576(one_chip):
+    """The social cells' consensus step: N=2^20, E=7N, d+1=4, with the
+    run ends, so the grid runs in order and carries runs in VMEM scratch;
+    the finish gathers at the run ends."""
+    n, e, d = 1 << 20, 7 << 20, 4
+    text = _compiled_text(
+        lambda *a: edge_scatter_pallas(*a[:5], seg_end=a[5],
+                                       interpret=False),
+        one_chip, ((n, d), jnp.float32), ((e, d), jnp.float32),
+        ((e,), jnp.bool_), ((e,), jnp.int32), ((e,), jnp.int32),
+        ((n,), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert "run_end_gather" in text and "scatter" not in text.replace(
+        "edge_scatter_pallas", "")
 
 
 def test_byz_trim_compiles_at_N4096(one_chip):

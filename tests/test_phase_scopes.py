@@ -10,6 +10,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import attacks, sweeps
@@ -156,3 +157,40 @@ def test_a_cache_miss_opens_a_build_span(tmp_path):
              if ev.name.startswith("repro.byz_grid.build")]
     # one for the runtime stack, one for the grid program
     assert len(names) == 2
+
+
+@pytest.mark.parametrize("sorted_edges", [True, False],
+                         ids=["sorted", "unsorted"])
+def test_run_end_gather_scope_sits_in_consensus(sorted_edges):
+    """The Pallas consensus step of a dst-sorted runtime finishes with a
+    gather under ``run_end_gather`` inside ``consensus`` (a name that is
+    no phase, so the benchmark's phase reader still counts it as
+    consensus); an unsorted runtime keeps the scatter finish."""
+    from chipbench import phases
+    from repro.core.graphs import EdgeList
+
+    model, rt, M, T = _social_setup()
+    if not sorted_edges:
+        el, rep = hier_edge_list([4] * 4, "complete", rep_choice="first")
+        perm = np.random.default_rng(0).permutation(el.E)
+        rt = social_runtime_from_edge_list(
+            EdgeList(src=el.src[perm], dst=el.dst[perm], n=el.n,
+                     valid=el.valid[perm]),
+            rep, drop_prob=0.3, gamma_period=2, B=2)
+    assert (rt.seg_end is not None) == sorted_edges
+    assert "run_end_gather" not in phases.PHASES
+    truth_probs = model.tables[:, model.truth, :].astype(jnp.float32)
+    text = _social_compiled.lower(
+        jax.random.PRNGKey(0), jax.random.PRNGKey(1), rt,
+        model.log_tables().astype(jnp.float32),
+        jnp.cumsum(truth_probs, axis=-1), truth=0, M=M, T=T,
+        store="log_ratio", backend="pallas").compile().as_text()
+    names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if "run_end_gather" in n.split("/")]
+    if not sorted_edges:
+        assert not names
+        return
+    assert names
+    for name in names:
+        assert "consensus" in name.split("/")
+        assert phases.phase_of(name) == "consensus"

@@ -8,6 +8,8 @@ inert; ``sort_by_dst`` is a pure relabeling (permutation round-trip); the
 mesh-sharded sweep engine returns exactly what the single-device vmap
 returns; and repeated ``run_byzantine_sweep`` calls do not retrace.
 """
+import functools
+import hashlib
 import json
 import os
 import subprocess
@@ -25,6 +27,7 @@ from repro.core.graphs import (
     link_schedule,
     random_strongly_connected,
     random_strongly_connected_edge_list,
+    run_ends,
     sort_by_dst,
     stack_edge_lists,
 )
@@ -43,6 +46,103 @@ def _sorted_graph(n, extra, seed):
     rng = np.random.default_rng(seed)
     el, perm, inv = sort_by_dst(edge_list(random_strongly_connected(n, extra, rng)))
     return el, perm, inv, rng
+
+
+# sha256 of each program's StableHLO body (header line dropped), lowered
+# from the tree before the run-end gather finish; see
+# TestRunEndGather.test_program_unchanged_without_seg_end
+_UNCHANGED_SHA256 = {
+    "kernel":
+        "14945e28fa50dbe04fa21a5cf1f37b25c419557ca4e2acb5faaaa7c85b8cc44e",
+    "xla_ref":
+        "416e30890723edce56d2acfd3eb8fd371680670abb22edaa537258b3e8f8a001",
+    "social_xla":
+        "d6b91c1cb544ef242db504c3a8c4ad94deed4a7de5dc8d1abe17fb10f9bb8a1d",
+    "social_async":
+        "ca1c1e6d6971259523ed6239c900c18d2f6c746e2bb2b5d9dd6740e19feaf669",
+    "social_graph_axis":
+        "2ce0adcb9f27e10473aebd4f2d5f00affc6e34e67cc4827e4a54620eb5584cbf",
+    "hps":
+        "fb22894e91e978d581666d805541288625df73b49d02906d4c7bebf0333565e3",
+    "pushsum_sparse":
+        "a353c57fac8b666548a3249311880664e513c077aa24565320c7df30011c3b76",
+}
+
+
+@functools.lru_cache(maxsize=1)
+def _lowered_programs() -> dict:
+    """StableHLO text of every program ``_UNCHANGED_SHA256`` names, at
+    small fixed shapes. The social runtimes come from a dst-sorted list,
+    so they carry run ends that these paths must not read."""
+    from repro.core.asyncrony import make_async_model
+    from repro.core.graphs import hier_edge_list, partition_edge_list
+    from repro.core.hps import _hps_compiled, hps_runtime_from_edge_list
+    from repro.core.plan import ExecutionPlan
+    from repro.core.signals import make_confused_model
+    from repro.core.social import (
+        _social_compiled,
+        _social_scan_core,
+        social_runtime_from_edge_list,
+    )
+    from repro.kernels.pushsum_edge import edge_scatter
+
+    out = {}
+    N, E, D = 29, 83, 5
+    f32, i32 = jnp.float32, jnp.int32
+    s = jax.ShapeDtypeStruct
+    args = (s((N, D), f32), s((E, D), f32), s((E,), jnp.bool_),
+            s((E,), i32), s((E,), i32))
+    out["kernel"] = edge_scatter_pallas.lower(
+        *args, block_e=16, interpret=True, indices_sorted=True).as_text()
+    out["xla_ref"] = jax.jit(
+        lambda *a: edge_scatter(*a, backend="xla", indices_sorted=True)
+    ).lower(*args).as_text()
+
+    el, rep = hier_edge_list([4] * 4, "complete", rep_choice="first")
+    rt = social_runtime_from_edge_list(el, rep, drop_prob=0.3,
+                                       gamma_period=2, B=2)
+    assert rt.seg_end is not None
+    model = make_confused_model(N=16, m=3, S=4, truth=0, confusion=0.5,
+                                sharpness=2.0, seed=1)
+    tp = model.tables[:, 0, :].astype(jnp.float32)
+    sargs = (jax.random.PRNGKey(0), jax.random.PRNGKey(1), rt,
+             model.log_tables().astype(jnp.float32), jnp.cumsum(tp, -1))
+    kw = dict(truth=0, M=4, T=4, store="log_ratio")
+    out["social_xla"] = _social_compiled.lower(
+        *sargs, backend="xla", **kw).as_text()
+    out["social_async"] = _social_compiled.lower(
+        *sargs, backend="pallas", async_=make_async_model(0.6, 2),
+        **kw).as_text()
+
+    sh = partition_edge_list(el, 2)
+    rt_sh = rt._replace(src=jnp.asarray(sh.src), dst=jnp.asarray(sh.dst),
+                        valid=jnp.asarray(sh.valid))
+    in_rt = jax.tree_util.tree_map(lambda _: None, rt)._replace(
+        src=0, dst=0, valid=0)
+    k0, k1, _, lt, cdf = sargs
+
+    def sharded(r):
+        return jax.vmap(
+            lambda r1: _social_scan_core(
+                k0, k1, r1, lt, cdf, backend="pallas", graph_axis="g",
+                n_shards=2, **kw)[1],
+            in_axes=(in_rt,), axis_name="g")(r)
+
+    out["social_graph_axis"] = jax.jit(sharded).lower(rt_sh).as_text()
+
+    hrt = hps_runtime_from_edge_list(el, rep, drop_prob=0.3, gamma_period=2,
+                                     B=2)
+    w = s((16, 2), f32)
+    out["hps"] = _hps_compiled.lower(
+        jax.random.PRNGKey(0), hrt, w, T=4, store="trajectory",
+        backend="pallas", dst_sorted=True).as_text()
+    plan = ExecutionPlan(backend="pallas", dst_sorted=True)
+    src, dst = jnp.asarray(el.src), jnp.asarray(el.dst)
+    out["pushsum_sparse"] = jax.jit(
+        lambda w: run_pushsum_sparse(w, src, dst, 4, drop_prob=0.2,
+                                     key=jax.random.PRNGKey(2),
+                                     plan=plan)[1]).lower(w).as_text()
+    return out
 
 
 class TestEdgeScatterKernel:
@@ -108,6 +208,102 @@ class TestEdgeScatterKernel:
         assert resolve_backend("auto") == expected
         with pytest.raises(ValueError):
             resolve_backend("cuda")
+
+
+def _run_end_case(case):
+    """A dst-sorted edge list for one run-end gather case:
+    ``(n, src, dst, valid, block_e)``."""
+    rng = np.random.default_rng(0)
+    n, block_e = 60, 128
+    deg = rng.integers(1, 10, n)
+    if case == "long_run":
+        deg[17] = 333                      # spans at least 3 blocks of 128
+    if case == "no_in_edges":
+        deg[[0, 5, 6, 31, n - 1]] = 0      # first, adjacent, last
+    dst = np.repeat(np.arange(n), deg).astype(np.int32)
+    if case == "ragged_E":
+        dst = dst[: (dst.size // block_e) * block_e + 37]
+    valid = np.ones(dst.size, bool)
+    if case == "padding_tail":             # as an e_max pad: dst = n - 1
+        dst = np.concatenate([dst, np.full(45, n - 1, np.int32)])
+        valid = np.concatenate([valid, np.zeros(45, bool)])
+    src = rng.integers(0, n, dst.size).astype(np.int32)
+    return n, src, dst, valid, block_e
+
+
+class TestRunEndGather:
+    @pytest.mark.parametrize("case", ["block_crossing", "long_run",
+                                      "no_in_edges", "padding_tail",
+                                      "ragged_E"])
+    def test_matches_xla_ref(self, case):
+        """The carried-run kernel and its run-end gather finish == gather +
+        where + segment_sum, on dst-sorted lists whose runs cross blocks."""
+        n, src, dst, valid, block_e = _run_end_case(case)
+        E = dst.size
+        seg_end = run_ends(dst, n)
+        crossing = (seg_end[seg_end >= 0] // block_e
+                    != np.searchsorted(dst, np.arange(n))[seg_end >= 0]
+                    // block_e)
+        assert crossing.any()              # some run spans a block boundary
+        if case == "ragged_E":
+            assert E % block_e
+        rng = np.random.default_rng(1)
+        D = 4
+        sigma = jnp.asarray(rng.normal(size=(n, D)).astype(np.float32))
+        rho = jnp.asarray(rng.normal(size=(E, D)).astype(np.float32))
+        live = jnp.asarray((rng.random(E) < 0.6) & valid)
+        args = (sigma, rho, live, jnp.asarray(src), jnp.asarray(dst))
+        rn_ref, rc_ref = edge_scatter_ref(*args)
+        rn_p, rc_p = edge_scatter_pallas(
+            *args, seg_end=jnp.asarray(seg_end), block_e=block_e,
+            interpret=True)
+        np.testing.assert_array_equal(np.asarray(rn_p), np.asarray(rn_ref))
+        np.testing.assert_allclose(np.asarray(rc_p), np.asarray(rc_ref),
+                                   rtol=1e-5, atol=1e-5)
+        no_in = seg_end < 0
+        assert (np.asarray(rc_p)[no_in] == 0).all()
+
+    def test_vmapped_scenarios_keep_their_own_carry(self):
+        """Batched over scenarios (the grid engines vmap the step), each
+        scenario's carry starts afresh at its first block."""
+        n, src, dst, valid, block_e = _run_end_case("long_run")
+        E, D = dst.size, 4
+        rng = np.random.default_rng(2)
+        sigma = jnp.asarray(rng.normal(size=(2, n, D)).astype(np.float32))
+        rho = jnp.asarray(rng.normal(size=(2, E, D)).astype(np.float32))
+        live = jnp.asarray(rng.random((2, E)) < 0.6)
+        src2 = jnp.asarray(np.stack([src, rng.permutation(src)]))
+        dst2 = jnp.asarray(np.stack([dst, dst]))
+        seg2 = jnp.asarray(np.stack([run_ends(dst, n)] * 2))
+        _, rc = jax.vmap(
+            lambda *a: edge_scatter_pallas(*a[:5], seg_end=a[5],
+                                           block_e=block_e, interpret=True)
+        )(sigma, rho, live, src2, dst2, seg2)
+        for k in range(2):
+            _, rc_ref = edge_scatter_ref(sigma[k], rho[k], live[k], src2[k],
+                                         dst2[k])
+            np.testing.assert_allclose(np.asarray(rc[k]), np.asarray(rc_ref),
+                                       rtol=1e-5, atol=1e-5)
+
+    def test_run_ends(self):
+        dst = np.array([0, 0, 2, 2, 2, 3], np.int32)
+        np.testing.assert_array_equal(run_ends(dst, 5), [1, -1, 4, 5, -1])
+        assert run_ends(dst[::-1], 5) is None
+
+    @pytest.mark.parametrize("path", sorted(_UNCHANGED_SHA256))
+    def test_program_unchanged_without_seg_end(self, path):
+        """Where no run ends reach the kernel the lowered program is the one
+        from before the run-end gather existed: the kernel called without
+        ``seg_end``, the XLA oracle, an edge-partitioned (graph_axis) or
+        async social scan and the XLA social scan, each given a runtime that
+        does hold run ends, the HPS scan and ``run_pushsum_sparse``. The
+        digests are of those programs' StableHLO before the change; an
+        intended change to one of them updates its digest here."""
+        text = _lowered_programs()[path]
+        body = text.split("\n", 1)[1]        # the header names the wrapper
+        assert "run_end_gather" not in body
+        assert hashlib.sha256(body.encode()).hexdigest() == \
+            _UNCHANGED_SHA256[path]
 
 
 class TestBackendTrajectoryEquivalence:

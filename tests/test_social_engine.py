@@ -513,3 +513,98 @@ class TestSocialSweep:
         assert res["devices"] == 4
         assert res["K"] == 12            # pad rows sliced off
         assert res["same"] and res["err"] == 0.0
+
+
+class TestRunEndGather:
+    """Runtimes of a dst-sorted edge index carry each receiver's run end,
+    and the Pallas consensus step then finishes with a gather there."""
+
+    @staticmethod
+    def _runtime(el, rep):
+        return social_runtime_from_edge_list(el, rep, drop_prob=0.3,
+                                             gamma_period=4, B=2)
+
+    def test_runtime_sets_seg_end_only_for_sorted_lists(self):
+        from repro.core.graphs import EdgeList, hier_edge_list
+
+        el, rep = hier_edge_list([8] * 4, "complete", rep_choice="first")
+        rt = self._runtime(el, rep)
+        want = np.searchsorted(el.dst, np.arange(el.n), side="right") - 1
+        np.testing.assert_array_equal(np.asarray(rt.seg_end), want)
+        # an e_max pad extends the last receiver's run
+        padded = social_runtime_from_edge_list(
+            el, rep, drop_prob=0.3, gamma_period=4, B=2, e_max=el.E + 5)
+        assert int(padded.seg_end[-1]) == el.E + 4
+        perm = np.random.default_rng(0).permutation(el.E)
+        shuffled = EdgeList(src=el.src[perm], dst=el.dst[perm], n=el.n,
+                            valid=el.valid[perm])
+        assert self._runtime(shuffled, rep).seg_end is None
+
+    def test_engine_with_and_without_seg_end_agree(self):
+        """96 complete 8-agent networks: E = 5376 spans two 4096-lane
+        kernel blocks, so a run is carried across the block boundary."""
+        from repro.core.graphs import hier_edge_list
+        from repro.core.plan import ExecutionPlan
+
+        el, rep = hier_edge_list([8] * 96, "complete", rep_choice="first")
+        assert el.E > 4096
+        rt = self._runtime(el, rep)
+        model = make_confused_model(N=el.n, m=3, S=4, truth=0,
+                                    confusion=0.5, sharpness=2.0, seed=3)
+        plan = ExecutionPlan(store="log_ratio", backend="pallas")
+        out = [run_social_runtime(model, r, 96, T=8, seed=7, plan=plan)
+               for r in (rt, rt._replace(seg_end=None))]
+        (a, b) = out
+        np.testing.assert_allclose(np.asarray(a.beliefs),
+                                   np.asarray(b.beliefs), atol=1e-6)
+        zm = [np.asarray(o.final_state.z)
+              / np.asarray(o.final_state.m)[:, None] for o in out]
+        np.testing.assert_allclose(zm[0], zm[1], atol=1e-6, rtol=1e-6)
+
+    def test_grid_stacks_sorted_and_mixed_runtimes(self, monkeypatch):
+        """A grid of sorted runtimes keeps their run ends; one unsorted
+        runtime among them drops every run end, and the grid still runs
+        one program whose rows agree with the sorted grid's."""
+        from repro.core import sweeps
+        from repro.core.graphs import EdgeList
+        from repro.core.social import make_social_runtime, stack_runtimes
+
+        model, cfgs = _grid_fixture()
+        cfgs = cfgs[:2]
+        from repro.core.plan import ExecutionPlan
+
+        kw = dict(T=12, seeds=[1, 2],
+                  plan=ExecutionPlan(backend="pallas"))
+        monkeypatch.setattr(sweeps, "_SOCIAL_RUNTIME_CACHE",
+                            sweeps._LRUCache(maxsize=4))
+        sorted_res = run_social_grid(model, cfgs, **kw)
+        stacked = list(sweeps._SOCIAL_RUNTIME_CACHE.values())[-1]
+        assert stacked.seg_end.shape == (2, 18)
+
+        def shuffled_second(cfg, e_max=None):
+            rt = make_social_runtime(cfg, e_max=e_max)
+            if cfg is not cfgs[1]:
+                return rt
+            el = cfg.edge_index()
+            perm = np.random.default_rng(0).permutation(el.E)
+            return social_runtime_from_edge_list(
+                EdgeList(src=el.src[perm], dst=el.dst[perm], n=el.n,
+                         valid=el.valid[perm]),
+                cfg.topo.rep_mask(), drop_prob=cfg.drop_prob,
+                gamma_period=cfg.gamma_period, B=cfg.B, e_max=e_max)
+
+        monkeypatch.setattr(sweeps, "make_social_runtime", shuffled_second)
+        monkeypatch.setattr(sweeps, "_SOCIAL_RUNTIME_CACHE",
+                            sweeps._LRUCache(maxsize=4))
+        mixed_res = run_social_grid(model, cfgs, **kw)
+        stacked = list(sweeps._SOCIAL_RUNTIME_CACHE.values())[-1]
+        assert stacked.seg_end is None
+        assert stack_runtimes([shuffled_second(c) for c in cfgs]).seg_end \
+            is None
+        rows = np.asarray(mixed_res.cfg) == 0     # the sorted config's rows
+        np.testing.assert_allclose(np.asarray(mixed_res.beliefs)[rows],
+                                   np.asarray(sorted_res.beliefs)[rows],
+                                   atol=1e-6)
+        np.testing.assert_allclose(np.asarray(mixed_res.log_ratio)[rows],
+                                   np.asarray(sorted_res.log_ratio)[rows],
+                                   atol=1e-5)
