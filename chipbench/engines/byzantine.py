@@ -33,7 +33,7 @@ NUMBERS = ("r_gap",)
 
 class Cell:
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 policy: str | None = None):
+                 policy: str | None = None, devices=None):
         from repro.core import attacks
         from repro.core.byzantine import ByzantineConfig
         from repro.core.graphs import make_hierarchy

@@ -46,7 +46,7 @@ def fault_params(f: dict | None):
 
 class Cell:
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 policy: str | None = None):
+                 policy: str | None = None, devices=None):
         from repro.core.graphs import hier_edge_list
         from repro.core.plan import ExecutionPlan
         from repro.core.signals import make_confused_model

@@ -7,7 +7,10 @@ the same names in both scan cores (``repro.core.social._social_scan_core``,
 
 * ``links``: the fault plane's draws and the round's edge mask;
 * ``consensus``: the social engine's push-sum edge step (the ``sigma[src]``
-  gather, the ``pushsum_edge`` kernel, the ``segment_sum`` finish);
+  gather, the ``pushsum_edge`` kernel, and the finish: a gather at each
+  receiver's run end, under the scope ``run_end_gather``, where the
+  runtime's edge index is dst-sorted, and the ``segment_sum`` scatter
+  elsewhere);
 * ``gossip``: the Byzantine engine's trimmed gossip (the neighbour
   gathers, the ``byz_trim`` kernel, the update);
 * ``innovation``: the signal draws and the accumulator update;
@@ -42,7 +45,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 if os.path.dirname(HERE) not in sys.path:
     sys.path.insert(0, os.path.dirname(HERE))
 
-from chipbench import trace, xplane  # noqa: E402
+from chipbench import trace  # noqa: E402
 from chipbench.trace import Span  # noqa: E402
 
 PHASES = ("links", "consensus", "gossip", "innovation", "fusion", "readout")
@@ -83,10 +86,15 @@ class Phases:
         self.spans = sorted(spans, key=lambda s: s.start_ns)
 
     @classmethod
+    def of(cls, ctx: trace.Context):
+        """The phases of a context that keeps its trace's ``tf_op`` map
+        and program spans (:meth:`chipbench.trace.Context.from_file`)."""
+        return cls(ctx, ctx.tf_ops, ctx.program_spans)
+
+    @classmethod
     def from_file(cls, path: str, **kw):
         """``kw``: :class:`chipbench.trace.Context`'s keyword arguments."""
-        ctx = trace.Context(*trace.load(path), **kw)
-        return cls(ctx, xplane.tf_ops(path), program_spans(path))
+        return cls.of(trace.Context.from_file(path, **kw))
 
     def phase_s(self, phase: str) -> float | None:
         """Leaf device seconds of one phase in the window, averaged over
@@ -180,11 +188,12 @@ class Phases:
         return out
 
     def breakdown(self, top: int = 10) -> dict:
-        """As :meth:`chipbench.trace.Context.breakdown`, with each op's
-        phase after its name (``fusion.31 fusion f32[...] @consensus``),
-        each idle gap named by the innermost span at its middle, all the
-        window's idle time split by the innermost span over each part
-        (``idle_by_span``), and :meth:`call_idle`."""
+        """The ``top`` leaves by device time (per chip), each with its phase
+        after its name (``fusion.31 fusion f32[...] @consensus``), and the
+        ``top`` longest idle gaps of the first chip, each named by the
+        innermost span at its middle; all the window's idle time split by
+        the innermost span over each part (``idle_by_span``), and
+        :meth:`call_idle`."""
         ctx = self.ctx
         per_op: dict[str, float] = {}
         for o in ctx.ops:
@@ -244,7 +253,7 @@ def profile(workload: str, seed: int, out_dir: str, *, seconds: float = 0.0,
     devs = run.devices(found["entry"]["chips"], require_tpu)
     run.enable_compile_cache()
     cell = run.load_module(found["engine_path"]).Cell(
-        found["config"], found["traffic"], seed)
+        found["config"], found["traffic"], seed, devices=devs)
     jax.block_until_ready(cell.call(0))
     host_s, call_s = [], []
     jax.profiler.start_trace(out_dir, profiler_options=options)

@@ -14,7 +14,12 @@ Everything is found by name, starting from the cell's entry in
   of the window are compared with the plain reference, and the limit of
   each number compared;
 * each per-layer metric ``chipbench/metrics/<name>.py`` listed for the
-  cell, read from the profiler's trace in a ``--trace 1`` run.
+  cell, read in a ``--trace 1`` run from the profiler's trace
+  (:class:`chipbench.trace.Context`: the device ops and their name
+  stacks, the benchmark's and the program's host spans).
+
+The cell is built over the devices its entry asks for (``chips``), the
+ones this run checked are there.
 
 A run: make the inputs from ``--seed`` and build the program's state
 (set-up), make one warm call of the cell's own shape, then run a closed
@@ -31,10 +36,12 @@ the window over the window's wall time; ``setup_s`` runs from the start of
 this process to the end of the warm call. The last line of standard
 output is one JSON object: ``correct``, ``attempted`` (calls in the
 window), ``failed`` (compared calls outside a limit), ``metrics``,
-``device``, ``breakdown`` in a traced run, and last ``checks``: each
-number compared with its limit. The same numbers and limits are the last
-lines of standard error. Without a TPU, or with fewer chips than the cell
-asks for, it exits non-zero and prints no result.
+``device``, ``breakdown`` in a traced run (the device ops that took most
+time, each with its phase, and the longest idle gaps, each named by the
+innermost host span: :meth:`chipbench.trace.Context.breakdown`), and last
+``checks``: each number compared with its limit. The same numbers and
+limits are the last lines of standard error. Without a TPU, or with fewer
+chips than the cell asks for, it exits non-zero and prints no result.
 """
 from __future__ import annotations
 
@@ -218,7 +225,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     counter = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
     engine = load_module(found["engine_path"])
-    cell = engine.Cell(found["config"], found["traffic"], seed)
+    cell = engine.Cell(found["config"], found["traffic"], seed,
+                       devices=devs)
     if cell_hook is not None:
         cell = cell_hook(cell)
     jax.block_until_ready(cell.call(0))                  # warm-up

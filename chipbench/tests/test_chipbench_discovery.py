@@ -4,7 +4,9 @@ configuration or a per-layer metric is added by adding files alone
 import json
 import os
 import re
+import shutil
 
+import jax
 import pytest
 
 from chipbench.tests import small
@@ -13,6 +15,7 @@ from chipbench import run
 BENCH = run.load_json(os.path.join(small.ROOT, "BENCHMARK.json"))
 NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}")
 UNIT = re.compile(r"[A-Za-z0-9_/%.-]{1,16}")
+SEED = 2 ** 31 + 3
 
 
 def _line(text):
@@ -85,31 +88,51 @@ def test_benchmark_file_keeps_the_format():
     assert len(json.dumps(BENCH)) < 64 * 1024
 
 
+def _real_dir(path):
+    """Turn the linked directory ``path`` of a small checkout into a real
+    one that links the same files; returns ``path``."""
+    real = os.path.realpath(path)
+    os.unlink(path)
+    os.mkdir(path)
+    for name in os.listdir(real):
+        os.symlink(os.path.join(real, name), os.path.join(path, name))
+    return path
+
+
+def _write_json(path, data):
+    with open(path, "w") as f:
+        json.dump(data, f)
+
+
 def _drop_in(root):
-    """Add a configuration, a traffic mix, a cell and a per-layer metric
-    to a small checkout, by adding files and entries alone."""
+    """Add to the full-size files of a checkout, by adding files and
+    entries alone: a configuration of an engine no cell uses (a copy of
+    the social driver under a new name), with its sizes file and its
+    faults file; a traffic mix with its sizes file; a four-chip cell; and a
+    per-layer metric."""
     bench_dir = os.path.join(root, "chipbench")
     conf = run.load_json(os.path.join(bench_dir, "configs",
                                       "hier-social-1m.json"))
-    conf.update(networks=16, drop_prob=0.1)
-    with open(os.path.join(bench_dir, "configs", "extra.json"), "w") as f:
-        json.dump(conf, f)
-    with open(os.path.join(bench_dir, "traffic", "extra-1x4.json"),
-              "w") as f:
-        json.dump({"scenarios_per_call": 1, "rounds_per_call": 4}, f)
-    with open(os.path.join(bench_dir, "workloads", "extra-cell.json"),
-              "w") as f:
-        json.dump({"calls": 1, "limits": {"belief_gap": 1e-5,
-                                          "z_over_m_gap": 1e-5,
-                                          "curve_gap": 1e-4}}, f)
-    # the metrics directory becomes a real one holding the same readers
-    # and one more
-    metrics = os.path.join(bench_dir, "metrics")
-    os.unlink(metrics)
-    os.mkdir(metrics)
-    for name in os.listdir(os.path.join(run.HERE, "metrics")):
-        os.symlink(os.path.join(run.HERE, "metrics", name),
-                   os.path.join(metrics, name))
+    conf.update(engine="social_copy", drop_prob=0.1)
+    _write_json(os.path.join(bench_dir, "configs", "extra.json"), conf)
+    _write_json(os.path.join(bench_dir, "traffic", "extra-1x8.json"),
+                {"scenarios_per_call": 1, "rounds_per_call": 16})
+    _write_json(os.path.join(bench_dir, "workloads", "extra-cell.json"),
+                {"calls": 1, "limits": {"belief_gap": 1e-5,
+                                        "z_over_m_gap": 1e-5,
+                                        "curve_gap": 1e-4}})
+    engines = _real_dir(os.path.join(bench_dir, "engines"))
+    shutil.copy(os.path.join(run.HERE, "engines", "social.py"),
+                os.path.join(engines, "social_copy.py"))
+    tests = os.path.join(bench_dir, "tests")
+    sizes = _real_dir(os.path.join(tests, "sizes"))
+    _write_json(os.path.join(sizes, "extra.json"), {"networks": 32})
+    _write_json(os.path.join(sizes, "extra-1x8.json"),
+                {"rounds_per_call": 8})
+    faults = _real_dir(os.path.join(tests, "faults"))
+    shutil.copy(os.path.join(small.HERE, "faults", "social.py"),
+                os.path.join(faults, "social_copy.py"))
+    metrics = _real_dir(os.path.join(bench_dir, "metrics"))
     with open(os.path.join(metrics, "calls_traced.py"), "w") as f:
         f.write("def read(ctx):\n    return float(ctx.calls)\n")
     bench = run.load_json(os.path.join(root, "BENCHMARK.json"))
@@ -117,24 +140,26 @@ def _drop_in(root):
                              "file": "chipbench/configs/extra.json",
                              "reduced": [], "why": "w"})
     bench["workloads"].append({"name": "extra-cell", "config": "extra",
-                               "traffic": "extra-1x4", "chips": 1,
+                               "traffic": "extra-1x8", "chips": 4,
                                "why": "w"})
     bench["per_layer"].append({"name": "calls_traced", "unit": "calls",
                                "better": "higher", "source": "host_clock",
                                "layer": "run_* entry points",
                                "moves": "agent_rounds_per_s",
                                "workloads": ["extra-cell"]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+    _write_json(os.path.join(root, "BENCHMARK.json"), bench)
     return bench
 
 
 def test_dropped_in_files_are_found_without_a_code_edit(tmp_path):
-    root = small.checkout(str(tmp_path))
-    bench = _drop_in(root)
+    root = small.layout(str(tmp_path))
+    _drop_in(root)
+    small.shrink(root)
+    bench = run.load_json(os.path.join(root, "BENCHMARK.json"))
     found = run.find_cell(bench, "extra-cell", root)
-    assert found["config"]["networks"] == 16
-    assert found["traffic"]["rounds_per_call"] == 4
+    assert found["config"]["networks"] == 32
+    assert found["traffic"]["rounds_per_call"] == 8
+    assert found["engine_path"].endswith("social_copy.py")
     assert [m["name"] for m in found["metrics"]] == ["calls_traced"]
     reader = run.load_module(os.path.join(found["metrics_dir"],
                                           "calls_traced.py"))
@@ -142,11 +167,48 @@ def test_dropped_in_files_are_found_without_a_code_edit(tmp_path):
     class Ctx:
         calls = 3
     assert reader.read(Ctx) == 3.0
-    # the new cell runs end to end and is correct
-    res = run.run_cell("extra-cell", 5, 0.2, False, root=root,
-                       require_tpu=False)
+    # the new cell asks for more chips than this process has: it runs end
+    # to end in a child on four CPU devices, and is correct
+    assert small.chips(root, "extra-cell") == 4 > len(jax.devices())
+    res = small.on_devices(4, small.run_small, root, "extra-cell", SEED)
     assert res["correct"], res["checks"]
+    assert res["device"]["count"] == 4
     assert res["metrics"]["agent_rounds_per_s"]["value"] > 0
+    # its own faults, found by its engine's name, each come out not correct
+    faults = small.faults(root, "extra-cell")
+    assert [f.__name__ for f in faults] == [
+        f.__name__ for f in small.faults(small.ROOT, "social-1m-drops")]
+    for fault in faults:
+        res = small.on_devices(4, small.run_small, root, "extra-cell", SEED,
+                               small.fault_ref(fault))
+        assert res["correct"] is False and res["failed"] >= 1, \
+            (fault.__name__, res["checks"])
+
+
+def test_a_cell_file_without_a_sizes_file_is_an_error(tmp_path):
+    root = small.layout(str(tmp_path))
+    _drop_in(root)
+    os.unlink(os.path.join(root, "chipbench", "tests", "sizes",
+                           "extra.json"))
+    with pytest.raises(FileNotFoundError, match="extra.json"):
+        small.shrink(root)
+
+
+def test_every_cell_file_has_sizes_and_every_engine_faults():
+    for rel in small.cell_files(small.ROOT):
+        sizes = small.sizes_file(small.ROOT, rel)
+        assert os.path.isfile(sizes), rel
+        assert isinstance(run.load_json(sizes), dict)
+    engines = [n[:-3] for n in os.listdir(os.path.join(run.HERE, "engines"))
+               if n.endswith(".py") and n != "__init__.py"]
+    assert engines
+    for engine in engines:
+        faults = run.load_module(os.path.join(
+            small.HERE, "faults", engine + ".py")).FAULTS
+        assert len(faults) >= 2, engine
+        assert all(callable(f) and f.__doc__ for f in faults)
+    for w in BENCH["workloads"]:
+        assert len(small.faults(small.ROOT, w["name"])) >= 2
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
@@ -155,13 +217,12 @@ def test_each_cell_builds_its_inputs_at_a_small_size(tmp_path, workload):
     found = run.find_cell(run.load_json(os.path.join(root,
                                                      "BENCHMARK.json")),
                           workload, root)
-    cell = run.load_module(found["engine_path"]).Cell(
-        found["config"], found["traffic"], 2 ** 31 + 11)
-    assert cell.agent_rounds_per_call > 0
-    assert cell.rounds_per_call == found["traffic"]["rounds_per_call"]
-    for name, args in cell.work.items():
-        mod = run.load_module(os.path.join(run.HERE, "work", name + ".py"))
-        assert mod.bytes_per_round(**args) > 0
+    cell = small.on_devices(small.chips(root, workload), small.small_cell,
+                            root, workload, 2 ** 31 + 11)
+    assert cell["agent_rounds_per_call"] > 0
+    assert cell["rounds_per_call"] == found["traffic"]["rounds_per_call"]
+    assert cell["bytes_per_round"]
+    assert all(b > 0 for b in cell["bytes_per_round"].values())
 
 
 def test_without_a_tpu_the_run_stops_before_a_result(capsys):

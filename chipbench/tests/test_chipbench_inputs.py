@@ -44,7 +44,8 @@ def test_confused_tables_are_the_programs_signal_model(N, confusion, seed):
 
 
 @pytest.mark.parametrize("N", [_config("hier-byzantine-4k")["N"],
-                               small.SMALL["configs/hier-byzantine-4k.json"]
+                               run.load_json(small.sizes_file(
+                                   small.ROOT, "hier-byzantine-4k.json"))
                                ["N"]])
 def test_byzantine_deployment_has_networks_outside_c(N):
     """The reference's healthy set is the program's, and on the seed the

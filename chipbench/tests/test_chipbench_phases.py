@@ -391,3 +391,104 @@ def test_profile_reads_the_program_spans_on_the_cpu(tmp_path):
         assert any(c.start_ns <= s.start_ns <= s.end_ns <= c.end_ns
                    for c in calls), s
     assert ph.host_prepare_ms_per_call() > 0
+
+
+# --- the phase metrics of BENCHMARK.json, read through the run's context ----
+
+# each recorded trace with what its cell ran (``chipbench/tests/record.py``)
+RECORDED_RUNS = {
+    "social-small": {"calls": 2, "rounds": 8, "work": {
+        "pushsum_edge": {"N": 1024, "E": 7168, "d": 3},
+        "social_innov": {"N": 1024, "m": 3, "S": 4}}},
+    "social-small-faults": {"calls": 2, "rounds": 8, "work": {
+        "pushsum_edge": {"N": 1024, "E": 7168, "d": 3},
+        "social_innov": {"N": 1024, "m": 3, "S": 4}}},
+    "byzantine-small": {"calls": 2, "rounds": 40, "work": {
+        "byz_trim": {"N": 64, "deg": 7, "P": 9, "K": 4}}},
+}
+# the metrics that read the phases
+PHASED = ("consensus_ms_per_round", "links_ms_per_round",
+          "gossip_ms_per_round", "innovation_ms_per_round",
+          "fusion_ms_per_round", "readout_ms_per_round",
+          "edge_scatter_roofline", "byz_trim_roofline",
+          "host_prepare_ms_per_call")
+
+
+def _run_kw(name):
+    rec = RECORDED_RUNS[name]
+    return dict(calls=rec["calls"], rounds=rec["rounds"],
+                host_call_s=[1e-3, 3e-3], work=rec["work"], peaks=V5E)
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_RUNS))
+def test_the_context_reads_the_existing_metrics_as_before(name):
+    """Keeping the ``tf_op`` map and the program's spans changes nothing
+    that a metric read without them, to the bit: every per-layer metric
+    of ``BENCHMARK.json`` that reads a value from the context without them
+    reads the same with them."""
+    path = os.path.join(DATA, name + ".xplane.pb")
+    old = trace.Context(*trace.load(path), **_run_kw(name))
+    new = trace.Context.from_file(path, **_run_kw(name))
+    assert new.tf_ops == xplane.tf_ops(path)
+    assert new.program_spans == phases.program_spans(path)
+    assert (new.t0, new.t1, new.window_s, new.busy_s, new.chips) == (
+        old.t0, old.t1, old.window_s, old.busy_s, old.chips)
+    assert new.ops == old.ops and new.spans == old.spans
+    bench = run.load_json(os.path.join(small.ROOT, "BENCHMARK.json"))
+    plain = [m["name"] for m in bench["per_layer"]
+             if _metric(m["name"], old) is not None]
+    assert "busy_ms_per_round" in plain and "host_ms_per_call" in plain
+    assert not set(plain) & set(PHASED)
+    for metric in plain:
+        assert _metric(metric, new) == _metric(metric, old), metric
+    # the breakdown keeps its numbers; only the names gain phases
+    b_new, b_old = new.breakdown(), old.breakdown()
+    assert [v for _, v in b_new["device_ops"]] == [
+        v for _, v in b_old["device_ops"]]
+    assert [v for _, v in b_new["idle_gaps"]] == [
+        v for _, v in b_old["idle_gaps"]]
+    assert [n.split(" @")[0] for n, _ in b_new["device_ops"]] == [
+        n for n, _ in b_old["device_ops"]]
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED_RUNS))
+def test_each_phase_metric_reads_what_the_phases_read(name):
+    path = os.path.join(DATA, name + ".xplane.pb")
+    ctx = trace.Context.from_file(path, **_run_kw(name))
+    want = phases.Phases.from_file(path, **_run_kw(name)).metrics()
+    for metric in PHASED:
+        assert _metric(metric, ctx) == want.get(metric), metric
+    read = {m for m in PHASED if _metric(m, ctx) is not None}
+    if name == "social-small":        # recorded before the scopes existed
+        assert read == set()
+    elif name == "social-small-faults":
+        assert read == set(PHASED) - {"gossip_ms_per_round",
+                                      "byz_trim_roofline"}
+    else:
+        assert read == set(PHASED) - {"consensus_ms_per_round",
+                                      "links_ms_per_round",
+                                      "edge_scatter_roofline"}
+    assert all(0 < _metric(m, ctx) < 100 for m in read
+               if m.endswith("_roofline"))
+
+
+def test_traced_run_names_the_breakdown_by_phase_and_span(tmp_path,
+                                                         monkeypatch):
+    """A traced run on the CPU (with the v5e's peak, as the CPU has none):
+    the phase metrics that need a TPU's ops are left out, the program's
+    spans are read, and the breakdown keeps its two lists, its gaps named
+    by the innermost span."""
+    from chipbench.work import peaks
+
+    monkeypatch.setattr(peaks, "peaks", lambda kind: V5E)
+    root = small.checkout(str(tmp_path))
+    res = run.run_cell("byzantine-4k-grid", 7, 0.2, True, root=root,
+                       require_tpu=False)
+    assert res["correct"], res["checks"]
+    assert res["metrics"]["host_prepare_ms_per_call"]["value"] > 0
+    assert res["metrics"]["host_prepare_ms_per_call"]["unit"] == "ms"
+    assert "gossip_ms_per_round" not in res["metrics"]
+    assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert 0 < len(res["breakdown"]["idle_gaps"]) <= 10
+    assert all(n == "between calls" or n.startswith(("chipbench.", "repro."))
+               for n, _ in res["breakdown"]["idle_gaps"])

@@ -25,8 +25,10 @@ the tests check it on hand-made events and on a recorded trace:
   averaged over the chips;
 * a kernel's time: the summed device time of the custom calls named after
   it, averaged over the chips, or ``None`` when there is none;
-* the breakdown: the leaves that took most device time, and the longest
-  idle gaps, each named by the host span it fell in.
+* the breakdown: the leaves that took most device time, each with its
+  phase, and the longest idle gaps, each named by the innermost host span
+  it fell in (:class:`chipbench.phases.Phases`, from the trace's ``tf_op``
+  map and the program's spans, which the context keeps).
 """
 from __future__ import annotations
 
@@ -123,12 +125,20 @@ def merge(intervals) -> list[tuple[float, float]]:
 class Context:
     """What a per-layer metric reader gets: the reduced trace of the
     window, and the run's own counts and work (``{work function:
-    its arguments}``, see ``chipbench/work``)."""
+    its arguments}``, see ``chipbench/work``); and, kept as they are for
+    :class:`chipbench.phases.Phases`, the trace's ``{op name: tf_op}``
+    (:func:`chipbench.xplane.tf_ops`) and the program's host spans
+    (:func:`chipbench.phases.program_spans`). Neither changes the window,
+    the ops or the busy time."""
 
     def __init__(self, ops: list[Op], spans: list[Span], *, calls: int,
-                 rounds: int, host_call_s: list, work: dict, peaks: dict):
+                 rounds: int, host_call_s: list, work: dict, peaks: dict,
+                 tf_ops: dict | None = None,
+                 program_spans: list[Span] | None = None):
         if not spans:
             raise ValueError("the trace holds none of the benchmark's spans")
+        self.tf_ops = dict(tf_ops or {})
+        self.program_spans = list(program_spans or [])
         self.t0 = min(s.start_ns for s in spans)
         self.t1 = max(s.end_ns for s in spans)
         self.spans = sorted(spans, key=lambda s: s.start_ns)
@@ -150,8 +160,17 @@ class Context:
     def from_dir(cls, trace_dir: str, *, device_kind: str, **kw):
         from chipbench.work.peaks import peaks
 
-        ops, spans = load(find_trace(trace_dir))
-        return cls(ops, spans, peaks=peaks(device_kind), **kw)
+        return cls.from_file(find_trace(trace_dir),
+                             peaks=peaks(device_kind), **kw)
+
+    @classmethod
+    def from_file(cls, path: str, **kw):
+        """The context of one trace file, with its ``tf_op`` map and the
+        program's spans; ``kw``: the keyword arguments but those two."""
+        from chipbench import phases, xplane
+
+        return cls(*load(path), tf_ops=xplane.tf_ops(path),
+                   program_spans=phases.program_spans(path), **kw)
 
     def kernel_s(self, kernel: str) -> float | None:
         """Device seconds of the custom calls named after ``kernel``,
@@ -163,29 +182,13 @@ class Context:
         return sum(o.end_ns - o.start_ns for o in hit) * 1e-9 / len(
             self.chips)
 
-    def host_span_at(self, t_ns: float) -> str:
-        """The benchmark span the host was in at ``t_ns``."""
-        for s in self.spans:
-            if s.start_ns <= t_ns < s.end_ns:
-                return s.name
-        return "between calls"
-
     def breakdown(self, top: int = 10) -> dict:
-        """The ``top`` leaves by device time (per chip), and the ``top``
-        longest idle gaps of the first chip, each named by the host span
-        its middle fell in."""
-        per_op: dict[str, float] = {}
-        for o in self.ops:
-            key = describe(o.name)
-            per_op[key] = per_op.get(key, 0.0) + (o.end_ns - o.start_ns)
-        n = len(self.chips)
-        device_ops = sorted(([k, v * 1e-9 / n] for k, v in per_op.items()),
-                            key=lambda kv: -kv[1])[:top]
-        busy = self._busy[self.chips[0]]
-        edges = [self.t0] + [x for iv in busy for x in iv] + [self.t1]
-        gaps = [(edges[k], edges[k + 1]) for k in range(0, len(edges), 2)
-                if edges[k + 1] > edges[k]]
-        gaps.sort(key=lambda g: g[0] - g[1])
-        idle = [[self.host_span_at((s + e) / 2), (e - s) * 1e-9]
-                for s, e in gaps[:top]]
-        return {"device_ops": device_ops, "idle_gaps": idle}
+        """The ``top`` leaves by device time (per chip), each with its
+        phase where the ``tf_op`` map gives one, and the ``top`` longest
+        idle gaps of the first chip, each named by the innermost host span,
+        the program's or the benchmark's, its middle fell in
+        (:meth:`chipbench.phases.Phases.breakdown`)."""
+        from chipbench.phases import Phases
+
+        b = Phases.of(self).breakdown(top)
+        return {"device_ops": b["device_ops"], "idle_gaps": b["idle_gaps"]}
