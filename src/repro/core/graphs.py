@@ -44,6 +44,8 @@ __all__ = [
     "run_ends",
     "EdgeShards",
     "partition_edge_list",
+    "chord_edge_list",
+    "is_dst_sorted",
     "block_complete_edge_list",
     "hier_edge_list",
     "random_strongly_connected_edge_list",
@@ -509,8 +511,21 @@ def sort_by_dst(el: EdgeList, return_offsets: bool = False):
     then (G, E)); padding edges keep ``valid=False`` and simply sort in with
     the genuine ``dst == 0`` run, where the core's ``mask & valid`` guard
     already silences them.
+
+    An index that is already dst-sorted (one O(E) check,
+    :func:`is_dst_sorted`) is returned as it is, with the identity
+    ``perm``/``inv``: no argsort. Callers that need only the sorted list
+    (:func:`partition_edge_list`) use :func:`_dst_sorted`, which makes no
+    E-length index at all.
     """
     dst = np.asarray(el.dst)
+    if is_dst_sorted(dst):
+        # already in order: the identity relabeling, and no argsort
+        perm = np.broadcast_to(np.arange(dst.shape[-1], dtype=np.int32),
+                               dst.shape).copy()
+        out = (el, perm, perm.copy())
+        return out if not return_offsets else out + (
+            _dst_offsets(dst, el.n),)
     perm = np.argsort(dst, axis=-1, kind="stable").astype(np.int32)
     inv = np.empty_like(perm)
     if perm.ndim == 1:
@@ -533,6 +548,18 @@ def sort_by_dst(el: EdgeList, return_offsets: bool = False):
     return sorted_el, perm, inv, offsets
 
 
+def is_dst_sorted(dst: np.ndarray) -> bool:
+    """True when every row of ``dst`` ((E,) or (G, E)) is ascending."""
+    dst = np.asarray(dst)
+    return bool(np.all(dst[..., 1:] >= dst[..., :-1]))
+
+
+def _dst_sorted(el: EdgeList) -> EdgeList:
+    """``el`` itself when its index is dst-sorted, else its stable sort by
+    receiver (:func:`sort_by_dst` without the relabeling arrays)."""
+    return el if is_dst_sorted(el.dst) else sort_by_dst(el)[0]
+
+
 def _dst_offsets(sorted_dst: np.ndarray, n: int) -> np.ndarray:
     """(..., N+1) int32 CSR offsets of a dst-sorted edge index."""
     grid = np.arange(n + 1)
@@ -550,12 +577,13 @@ def run_ends(dst: np.ndarray, n: int) -> np.ndarray | None:
     per-round draws). The run-end gather finish of
     :mod:`repro.kernels.pushsum_edge` reads these lanes."""
     dst = np.asarray(dst)
-    if np.any(dst[1:] < dst[:-1]):
+    if not is_dst_sorted(dst):
         return None
-    grid = np.arange(n)
-    lo = np.searchsorted(dst, grid, side="left")
-    hi = np.searchsorted(dst, grid, side="right")
-    return np.where(hi > lo, hi - 1, -1).astype(np.int32)
+    out = np.full(n, -1, np.int32)
+    if dst.size:
+        ends = np.append(np.flatnonzero(dst[1:] != dst[:-1]), dst.size - 1)
+        out[dst[ends]] = ends
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -579,13 +607,20 @@ class EdgeShards:
     ``boundary`` is the halo index: ``boundary[..., v]`` is True iff
     receiver ``v``'s in-edge run is split across a shard cut — the only
     nodes whose per-step ``recv`` is a genuine multi-shard sum (interior
-    nodes add exact ``+0.0`` partials from foreign shards), i.e. the only
-    nodes where the combined result can differ from the single-device
-    reference by floating-point reduce order.
+    nodes add exact ``+0.0`` partials from foreign shards).
+
+    ``run_ends[..., k, v]`` is the slot, within shard ``k``, where
+    receiver ``v``'s run ends: its last in-edge there, or for the shard's
+    last receiver the last padding slot after it (padding keeps that
+    receiver); ``-1`` where ``v`` has no real in-edge on the shard. A split
+    receiver's earlier part ends at its shard's last real slot (a shard cut
+    inside a run is full). The Pallas lowering finishes each shard with a
+    gather at these slots (:meth:`run_end_windows`) instead of a scatter
+    of its partials.
 
     Fields carry a leading graph axis (G, S, E_shard) when built from a
     batched :class:`EdgeList`, else (S, E_shard); ``boundary`` is
-    correspondingly (G, N) or (N,).
+    correspondingly (G, N) or (N,), ``run_ends`` (G, S, N) or (S, N).
     """
 
     src: np.ndarray       # (..., S, E_shard) int32
@@ -594,6 +629,7 @@ class EdgeShards:
     n: int                # node count
     e_total: int          # edge count of the (padded) source EdgeList
     boundary: np.ndarray  # (..., N) bool halo index — receivers split by cuts
+    run_ends: np.ndarray  # (..., S, N) int32 per-shard run ends, or -1
 
     @property
     def n_shards(self) -> int:
@@ -607,7 +643,7 @@ class EdgeShards:
     @property
     def e_pad(self) -> int:
         """Total padded edge count ``n_shards * e_shard`` — the edge count
-        of the bit-exact single-device reference program."""
+        of the single-device program over :meth:`padded_edge_list`."""
         return self.n_shards * self.e_shard
 
     @property
@@ -618,64 +654,126 @@ class EdgeShards:
         """Concatenate the shards back into one (..., E_pad) EdgeList.
 
         This — not the original pre-partition edge list — is the
-        single-device program the sharded run is bit-identical to: the
+        single-device program the sharded run is compared with: the
         per-round (E_pad,) Bernoulli mask each device draws (and windows
         into) indexes the *padded* slots, and jax's counter-based bits have
         no prefix property, so the original unpadded list only matches when
-        ``e_pad == E`` or ``drop_prob == 0``.
+        ``e_pad == E`` or ``drop_prob == 0``. The two agree up to the order
+        in which each receiver's sum is formed: XLA rewrites the
+        single-device ``z * share + segment_sum(...)`` into a scatter onto
+        ``z * share``, which the sharded program's halo ``psum`` between
+        the sum and the add rules out, so the last bits can differ at any
+        receiver (see ``run_pushsum_sweep``).
         """
         flat = lambda a: a.reshape(*a.shape[:-2], -1)
         return EdgeList(src=flat(self.src), dst=flat(self.dst), n=self.n,
                         valid=flat(self.valid))
+
+    def run_end_windows(self) -> tuple[np.ndarray, np.ndarray]:
+        """``(lo, ends)``: each shard's run ends over one window of ``W``
+        receivers, the same ``W`` for every shard (the widest receiver
+        range a shard holds): ``ends[..., k, i]`` is ``run_ends[..., k,
+        lo[..., k] + i]``. A shard's receivers are contiguous, so its window
+        holds every run that ends on it; ``lo`` (int32, (..., S)) is kept
+        at most ``N - W``."""
+        held = self.run_ends >= 0
+        idx = np.arange(self.n)
+        first = np.where(held, idx, self.n).min(axis=-1)
+        last = np.where(held, idx, -1).max(axis=-1)
+        width = max(int((last - first + 1).max(initial=1)), 1)
+        lo = np.minimum(np.where(last >= 0, first, 0), self.n - width)
+        ends = np.take_along_axis(
+            self.run_ends, lo[..., None] + np.arange(width), axis=-1)
+        return lo.astype(np.int32), ends
+
+
+def _shard_run_ends(dst_s: np.ndarray, real: np.ndarray,
+                    n: int) -> np.ndarray:
+    """(S, N) int32 run ends of dst-sorted shards ``dst_s`` (S, E_shard)
+    whose first ``real[k]`` slots are real edges; ``-1`` elsewhere. The
+    padding after a shard's real slots keeps its last receiver, whose run
+    therefore ends at the shard's last slot."""
+    out = np.stack([run_ends(d[:w], n) for d, w in zip(dst_s, real)])
+    for k, w in enumerate(real):
+        if w:
+            out[k, dst_s[k, w - 1]] = dst_s.shape[1] - 1
+    return out
 
 
 def partition_edge_list(el: EdgeList, n_shards: int) -> EdgeShards:
     """Cut an edge list into ``n_shards`` dst-contiguous, capacity-padded
     shards for the edge-partitioned (graph-axis) execution mode.
 
-    The index is (re-)sorted by destination, cut at the balanced positions
-    ``k * ceil(E / n_shards)`` (cuts may fall mid-segment — the receivers
-    split that way are recorded in the ``boundary`` halo index), and each
-    shard's tail is padded with inert dst-sorted edges up to the common
-    capacity. Batched edge lists partition every topology draw
-    independently under one shared capacity, so a whole scenario grid rides
-    a single (G, S, E_shard) layout.
+    The index is sorted by destination (an index that is sorted already is
+    used as it is), cut at the balanced positions ``k * ceil(E /
+    n_shards)`` (cuts may fall mid-segment — the receivers split that way
+    are recorded in the ``boundary`` halo index), and the tail is padded
+    with inert dst-sorted edges up to the common capacity; the shards are
+    then one reshape of the padded index (a view where no padding is
+    needed). Batched edge lists partition every topology draw
+    independently under one shared capacity, so a whole scenario grid
+    rides a single (G, S, E_shard) layout.
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
 
     def one(src, dst, valid):
-        (s_el, _, _) = sort_by_dst(
-            EdgeList(src=src, dst=dst, n=el.n, valid=valid))[:3]
+        s_el = _dst_sorted(EdgeList(src=src, dst=dst, n=el.n, valid=valid))
         E = s_el.E
         e_shard = max(-(-E // n_shards), 1)
-        src_s = np.zeros((n_shards, e_shard), np.int32)
-        dst_s = np.zeros((n_shards, e_shard), np.int32)
-        val_s = np.zeros((n_shards, e_shard), bool)
+        pad = n_shards * e_shard - E
+        src_s, dst_s, val_s = s_el.src, s_el.dst, s_el.valid
+        if pad:
+            # tail padding keeps the last real dst so every shard stays
+            # sorted; src 0 / valid False keep it inert
+            src_s = np.concatenate([src_s, np.zeros(pad, np.int32)])
+            dst_s = np.concatenate([dst_s, np.full(pad, dst_s[E - 1],
+                                                   np.int32)])
+            val_s = np.concatenate([val_s, np.zeros(pad, bool)])
+        shape = (n_shards, e_shard)
+        src_s, dst_s, val_s = (np.asarray(a).reshape(shape)
+                               for a in (src_s, dst_s, val_s))
+        # a cut strictly inside a receiver's run marks it boundary
         bnd = np.zeros(el.n, bool)
-        for k in range(n_shards):
-            lo, hi = k * e_shard, min((k + 1) * e_shard, E)
-            w = max(hi - lo, 0)
-            if w:
-                src_s[k, :w] = s_el.src[lo:hi]
-                dst_s[k, :w] = s_el.dst[lo:hi]
-                val_s[k, :w] = s_el.valid[lo:hi]
-                # tail padding keeps the shard's last real dst so the
-                # shard stays sorted; src 0 / valid False keep it inert
-                dst_s[k, w:] = s_el.dst[hi - 1]
-            # a cut strictly inside a receiver's run marks it boundary
-            if 0 < lo < E and s_el.dst[lo - 1] == s_el.dst[lo]:
-                bnd[s_el.dst[lo]] = True
-        return src_s, dst_s, val_s, bnd
+        lo = np.arange(1, n_shards) * e_shard
+        lo = lo[lo < E]
+        split = s_el.dst[lo - 1] == s_el.dst[lo]
+        bnd[s_el.dst[lo[split]]] = True
+        real = np.clip(E - np.arange(n_shards) * e_shard, 0, e_shard)
+        return src_s, dst_s, val_s, bnd, _shard_run_ends(dst_s, real, el.n)
 
     if el.is_batched:
         parts = [one(el.src[g], el.dst[g], el.valid[g])
                  for g in range(el.src.shape[0])]
-        src_s, dst_s, val_s, bnd = (np.stack(x) for x in zip(*parts))
+        src_s, dst_s, val_s, bnd, ends = (np.stack(x) for x in zip(*parts))
     else:
-        src_s, dst_s, val_s, bnd = one(el.src, el.dst, el.valid)
+        src_s, dst_s, val_s, bnd, ends = one(el.src, el.dst, el.valid)
     return EdgeShards(src=src_s, dst=dst_s, valid=val_s, n=el.n,
-                      e_total=el.E, boundary=bnd)
+                      e_total=el.E, boundary=bnd, run_ends=ends)
+
+
+def chord_edge_list(n_bits: int, fingers: int | None = None) -> EdgeList:
+    """The Chord overlay (Stoica et al., SIGCOMM 2001, section 4) on a fully
+    populated ring of ``N = 2^n_bits`` identifiers, as a dst-sorted
+    :class:`EdgeList`.
+
+    Peer ``v``'s i-th finger is ``v + 2^i mod N`` for ``i < fingers``
+    (default ``n_bits``, the whole finger table), and every peer pushes to
+    its fingers: the in-edges of ``v`` come from ``v - 2^i mod N``. Edge
+    ``e = v * L + i`` (``L = fingers``) is ``v - 2^i -> v``, so the index
+    is sorted by receiver by construction, every peer has in- and
+    out-degree ``L``, and finger 0 (the successor ring) makes the graph
+    strongly connected. Built in O(E) with no sort and no per-node loop.
+    """
+    L = n_bits if fingers is None else fingers
+    if not 1 <= L <= n_bits:
+        raise ValueError(f"need 1 <= fingers <= n_bits, got {L}, {n_bits}")
+    N = 1 << n_bits
+    dst = np.repeat(np.arange(N, dtype=np.int32), L)
+    src = dst.reshape(N, L) - (np.int32(1) << np.arange(L, dtype=np.int32))
+    np.bitwise_and(src, np.int32(N - 1), out=src)            # mod N
+    return EdgeList(src=src.reshape(-1), dst=dst, n=N,
+                    valid=np.ones(N * L, bool))
 
 
 def random_strongly_connected_edge_list(

@@ -265,6 +265,7 @@ def sparse_pushsum_step(
     abuf: AsyncBuffer | None = None,
     staleness: jnp.ndarray | None = None,
     seg_end: jnp.ndarray | None = None,
+    seg_lo: jnp.ndarray | None = None,
 ) -> SparsePushSumState | tuple[SparsePushSumState, AsyncBuffer]:
     """One fast-robust-push-sum iteration on edge-list state.
 
@@ -291,13 +292,16 @@ def sparse_pushsum_step(
     state carry only this device's (E_shard,) slice of a
     :func:`repro.core.graphs.partition_edge_list` layout while node state
     stays replicated. Each shard computes its local receiver partials and
-    the halo combine is one ``lax.psum`` pair over ``graph_axis`` —
-    interior receivers (all in-edges on one shard) get exact ``+0.0``
-    contributions from foreign shards; only boundary receivers (in-edge
-    runs split by a shard cut) see a genuine multi-operand sum, which is
-    where reduce-order fp differences vs. the single-device reference can
-    appear. When ``share`` is not supplied the local out-degree is psum'd
-    the same way before the reciprocal.
+    the halo combine is one ``lax.psum`` pair over ``graph_axis`` (under
+    the scope ``halo``) — interior receivers (all in-edges on one shard)
+    get exact ``+0.0`` contributions from foreign shards; only boundary
+    receivers (in-edge runs split by a shard cut) see a genuine
+    multi-operand sum. The single-device step can still differ in the
+    last bits at any receiver: there XLA folds the kept share into the
+    receivers' scatter-add, which the psum rules out here (see
+    :func:`repro.core.sweeps.run_pushsum_sweep`). When ``share`` is not
+    supplied the local out-degree is psum'd the same way before the
+    reciprocal.
 
     ``dst_sorted=True`` asserts the edge index is dst-sorted (the
     partitioner's layout, or :func:`graphs.sort_by_dst` output) and lets
@@ -306,8 +310,13 @@ def sparse_pushsum_step(
     ``seg_end`` ((N,) int32, :func:`graphs.run_ends` of a dst-sorted index)
     lets the Pallas lowering finish each receiver's sum with a gather at
     its run end instead of the ``segment_sum`` scatter (see
-    :mod:`repro.kernels.pushsum_edge`). Single-device only; the XLA and
-    async lowerings do not read it, and ``None`` keeps the scatter.
+    :mod:`repro.kernels.pushsum_edge`); the XLA and async lowerings do not
+    read it, and ``None`` keeps the scatter. With ``seg_lo`` (an int32
+    scalar) it covers only the receivers ``seg_lo .. seg_lo + W - 1``
+    (``W`` its length), and every other receiver's sum is zero: the form an
+    edge shard passes under ``graph_axis`` (its shard's window of
+    :meth:`graphs.EdgeShards.run_end_windows`), so that each device gathers
+    only the receivers whose runs end on it before the halo combine.
 
     **Precision policy** (``policy=``, see :mod:`repro.core.precision`):
     persistent state stays in the storage dtype, elementwise staging runs
@@ -362,9 +371,6 @@ def sparse_pushsum_step(
 
     if halo not in HALO_VARIANTS:
         raise ValueError(f"halo={halo!r} not in {HALO_VARIANTS}")
-    if seg_end is not None and graph_axis is not None:
-        raise ValueError("seg_end indexes the whole edge list; an edge "
-                         "shard has run ends of its own")
     pol = None if policy is None else resolve_policy(policy)
     z, m, sigma, sigma_m, rho, rho_m = state
     n = z.shape[0]
@@ -436,7 +442,7 @@ def sparse_pushsum_step(
         rho_cat = jnp.concatenate([rho, rho_m[:, None]], axis=1)
         rho_cat_new, recv_cat = edge_scatter(
             sigma_cat, rho_cat, live, src, dst, backend="pallas",
-            seg_end=seg_end, indices_sorted=dst_sorted,
+            seg_end=seg_end, seg_lo=seg_lo, indices_sorted=dst_sorted,
             accum_dtype=accum_name,
         )
         rho_new, rho_m_new = rho_cat_new[:, :-1], rho_cat_new[:, -1]
@@ -453,29 +459,8 @@ def sparse_pushsum_step(
             num_segments=n, indices_are_sorted=dst_sorted,
         )
     if graph_axis is not None:
-        if halo == "scatter":
-            # reduce-scatter + quantize + all-gather: each device reduces
-            # its own N/n_shards row block, so the gathered payload can ride
-            # the storage dtype (the reduction already happened in accum)
-            cat = jnp.concatenate([recv, recv_m[:, None]], axis=1)
-            pad_n = (-n) % n_shards
-            if pad_n:
-                cat = jnp.pad(cat, ((0, pad_n), (0, 0)))
-            part = jax.lax.psum_scatter(
-                cat, graph_axis, scatter_dimension=0, tiled=True
-            )
-            cat = jax.lax.all_gather(
-                part.astype(st_dt), graph_axis, axis=0, tiled=True
-            ).astype(ac_dt)
-            if pad_n:
-                cat = cat[:n]
-            recv, recv_m = cat[:, :-1], cat[:, -1]
-        else:
-            # halo combine: interior receivers add exact +0.0 partials,
-            # boundary receivers (see EdgeShards.boundary) sum their split
-            # in-edge runs
-            recv = jax.lax.psum(recv, graph_axis)
-            recv_m = jax.lax.psum(recv_m, graph_axis)
+        recv, recv_m = _halo(recv, recv_m, graph_axis, halo, n_shards,
+                             st_dt, ac_dt)
 
     # --- integrate (accum dtype) ---
     z_p = (z.astype(cp_dt) * share[:, None]).astype(ac_dt) + recv
@@ -511,6 +496,35 @@ def sparse_pushsum_step(
     if abuf is not None:
         return new, abuf_new
     return new
+
+
+def _halo(recv, recv_m, graph_axis, halo, n_shards, st_dt, ac_dt):
+    """The halo combine of the edge-partitioned step, under the scope
+    ``halo``: every shard's receiver partials summed over ``graph_axis``
+    (see :func:`sparse_pushsum_step`'s ``halo=``)."""
+    with jax.named_scope("halo"):
+        if halo == "scatter":
+            # reduce-scatter + quantize + all-gather: each device reduces
+            # its own N/n_shards row block, so the gathered payload can ride
+            # the storage dtype (the reduction already happened in accum)
+            n = recv.shape[0]
+            cat = jnp.concatenate([recv, recv_m[:, None]], axis=1)
+            pad_n = (-n) % n_shards
+            if pad_n:
+                cat = jnp.pad(cat, ((0, pad_n), (0, 0)))
+            part = jax.lax.psum_scatter(
+                cat, graph_axis, scatter_dimension=0, tiled=True
+            )
+            cat = jax.lax.all_gather(
+                part.astype(st_dt), graph_axis, axis=0, tiled=True
+            ).astype(ac_dt)
+            if pad_n:
+                cat = cat[:n]
+            return cat[:, :-1], cat[:, -1]
+        # interior receivers add exact +0.0 partials, boundary receivers
+        # (see EdgeShards.boundary) sum their split in-edge runs
+        return (jax.lax.psum(recv, graph_axis),
+                jax.lax.psum(recv_m, graph_axis))
 
 
 _HALF_DTYPES = (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float16))

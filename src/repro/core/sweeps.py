@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .attacks import Attack
 from .asyncrony import (
@@ -451,8 +451,8 @@ def _sweep_sharded(mesh: Mesh, data_axis: str, T: int, B: int, backend: str,
     streams=(("link", lambda t: t),),
     caches=("pushsum.sweep2d-jit",),
 )
-def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, drop_b, seed_b,
-                             fault_b=None, *,
+def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, lo_sh, ends_sh,
+                             drop_b, seed_b, fault_b=None, *,
                              T, B, backend, graph_axis, n_shards,
                              policy=None, halo="psum"):
     """Per-device scenario batch of the edge-partitioned (2-D mesh) sweep.
@@ -461,22 +461,31 @@ def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, drop_b, seed_b,
     a ``jax.vmap(axis_name=graph_axis)`` emulation on one device — with
     ``w`` replicated, the edge arrays carrying this device's
     (Kb, 1, E_shard) slice of a :func:`graphs.partition_edge_list` layout,
+    its run-end window (``lo_sh`` (Kb, 1), ``ends_sh`` (Kb, 1, W):
+    :meth:`graphs.EdgeShards.run_end_windows`, read by the Pallas finish),
     and the scenario coordinates (Kb,) sharded over data only. Node state
     is replicated over the graph axis; each round's receiver partials (and
     the hoisted out-degree / final mass invariant) are combined with psum
     inside :func:`sparse_pushsum_step`, so every graph-shard device holds
     identical node state and the outputs are graph-replicated.
+
+    A round runs under the phase scopes ``links`` (the mask draw and this
+    shard's window of it), ``consensus`` (the edge step: the gather, the
+    kernel, the finish, and the halo combine under the nested scope
+    ``halo``) and ``readout`` (the round's error).
     """
-    e_shard = src_sh.shape[-1]
+    kb, e_shard = src_sh.shape[0], src_sh.shape[-1]
     # (Kb, 1, Es) under shard_map, (Kb, Es) under the vmap emulation
-    src_sh = src_sh.reshape(src_sh.shape[0], e_shard)
-    dst_sh = dst_sh.reshape(dst_sh.shape[0], e_shard)
-    valid_sh = valid_sh.reshape(valid_sh.shape[0], e_shard)
+    src_sh = src_sh.reshape(kb, e_shard)
+    dst_sh = dst_sh.reshape(kb, e_shard)
+    valid_sh = valid_sh.reshape(kb, e_shard)
+    lo_sh = lo_sh.reshape(kb)
+    ends_sh = ends_sh.reshape(kb, ends_sh.shape[-1])
     target = w.mean(axis=0)
     w_sum = w.sum(axis=0)
     n = w.shape[0]
 
-    def single(src, dst, valid, drop, seed, fault=None):
+    def single(src, dst, valid, lo, ends, drop, seed, fault=None):
         key = jax.random.PRNGKey(seed)
         state0 = init_sparse_state(w, e_shard, policy=policy)
         # loop invariant: global out-degree = psum of shard-local counts
@@ -485,19 +494,26 @@ def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, drop_b, seed_b,
         )
         share = 1.0 / (d_out + 1.0)
 
-        if fault is None:
-            def body(state, t):
-                mask = shard_edge_mask(
-                    key, t, e_shard, drop, B,
-                    graph_axis=graph_axis, n_shards=n_shards,
-                )
+        def step(state, mask, fs=None):
+            with jax.named_scope("consensus"):
                 new = sparse_pushsum_step(
                     state, mask, src, dst, valid, backend,
                     share=share, graph_axis=graph_axis, dst_sorted=True,
                     policy=policy, halo=halo, n_shards=n_shards,
+                    faults=fs, seg_end=ends, seg_lo=lo,
                 )
+            with jax.named_scope("readout"):
                 err = jnp.abs(sparse_ratios(new) - target).max()
-                return new, err
+            return new, err
+
+        if fault is None:
+            def body(state, t):
+                with jax.named_scope("links"):
+                    mask = shard_edge_mask(
+                        key, t, e_shard, drop, B,
+                        graph_axis=graph_axis, n_shards=n_shards,
+                    )
+                return step(state, mask)
 
             final, errs = jax.lax.scan(
                 body, state0, jnp.arange(T, dtype=jnp.uint32)
@@ -508,18 +524,17 @@ def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, drop_b, seed_b,
                 # like shard_edge_mask, so realizations are identical at
                 # every shard count
                 state, fs = carry
-                fs = step_faults(key, t, fault, fs, engine=ENGINE_PUSHSUM,
-                                 graph_axis=graph_axis, n_shards=n_shards)
-                u = edge_uniforms(key, t, e_shard,
-                                  graph_axis=graph_axis, n_shards=n_shards)
-                mask = faulty_edge_mask(u, t, fault, fs, src, dst, drop, B)
-                new = sparse_pushsum_step(
-                    state, mask, src, dst, valid, backend,
-                    share=share, graph_axis=graph_axis, dst_sorted=True,
-                    policy=policy, halo=halo, n_shards=n_shards,
-                    faults=fs,
-                )
-                err = jnp.abs(sparse_ratios(new) - target).max()
+                with jax.named_scope("links"):
+                    fs = step_faults(key, t, fault, fs,
+                                     engine=ENGINE_PUSHSUM,
+                                     graph_axis=graph_axis,
+                                     n_shards=n_shards)
+                    u = edge_uniforms(key, t, e_shard,
+                                      graph_axis=graph_axis,
+                                      n_shards=n_shards)
+                    mask = faulty_edge_mask(u, t, fault, fs, src, dst,
+                                            drop, B)
+                new, err = step(state, mask, fs)
                 return (new, fs), err
 
             (final, _), errs = jax.lax.scan(
@@ -531,17 +546,14 @@ def _sweep_edge_sharded_body(w, src_sh, dst_sh, valid_sh, drop_b, seed_b,
         ) - w_sum
         return errs, sparse_ratios(final), gap
 
-    if fault_b is None:
-        return jax.vmap(single, in_axes=(0, 0, 0, 0, 0))(
-            src_sh, dst_sh, valid_sh, drop_b, seed_b
-        )
-    return jax.vmap(single, in_axes=(0, 0, 0, 0, 0, 0))(
-        src_sh, dst_sh, valid_sh, drop_b, seed_b, fault_b
-    )
+    args = (src_sh, dst_sh, valid_sh, lo_sh, ends_sh, drop_b, seed_b)
+    if fault_b is not None:
+        args += (fault_b,)
+    return jax.vmap(single)(*args)
 
 
-def _sweep2d_emulated(w, src_k, dst_k, valid_k, drop_b, seed_b,
-                      fault_b=None, *,
+def _sweep2d_emulated(w, src_k, dst_k, valid_k, lo_k, ends_k, drop_b,
+                      seed_b, fault_b=None, *,
                       T, B, backend, graph_axis, n_shards,
                       policy=None, halo="psum"):
     """Single-device oracle of the 2-D mesh program: ``vmap(axis_name=)``
@@ -557,10 +569,10 @@ def _sweep2d_emulated(w, src_k, dst_k, valid_k, drop_b, seed_b,
             graph_axis=graph_axis, n_shards=n_shards,
             policy=policy, halo=halo,
         ),
-        in_axes=(None, 1, 1, 1, None, None, None),
+        in_axes=(None, 1, 1, 1, 1, 1, None, None, None),
         out_axes=0,
         axis_name=graph_axis,
-    )(w, src_k, dst_k, valid_k, drop_b, seed_b, fault_b)
+    )(w, src_k, dst_k, valid_k, lo_k, ends_k, drop_b, seed_b, fault_b)
     return errs[0], finals[0], gaps[0]
 
 
@@ -590,9 +602,8 @@ def _sweep_sharded_2d(mesh: Mesh, data_axis: str, graph_axis: str,
         graph_axis=graph_axis, n_shards=n_shards,
         policy=policy, halo=halo,
     )
-    in_specs = (specs["replicated"], specs["edge_shards"],
-                specs["edge_shards"], specs["edge_shards"],
-                specs["scenario"], specs["scenario"])
+    in_specs = (specs["replicated"],) + (specs["edge_shards"],) * 5 + (
+        specs["scenario"], specs["scenario"])
     if has_faults:
         in_specs += (FaultModel(
             *([specs["scenario"]] * len(FaultModel._fields))),)
@@ -644,12 +655,19 @@ def run_pushsum_sweep(
     takes single scenarios past N ~ 1e5. With ``mesh`` given its
     ``graph_axis`` extent must equal S; without a mesh the shard axis runs
     as a single-device ``vmap(axis_name=)`` emulation — the bit-identity
-    oracle of the mesh path. Either way results are bit-identical to the
-    plain path on ``EdgeShards.padded_edge_list()`` up to boundary-node
-    reduce order (see :class:`graphs.EdgeShards`); when ``S * e_shard``
-    exceeds E the padded mask draw re-indexes edge slots, so compare
-    against the padded list, not the original (threefry bits have no
-    prefix property).
+    oracle of the mesh path. Either way each shard's Pallas step finishes
+    with a gather at its receivers' run ends
+    (:meth:`graphs.EdgeShards.run_end_windows`), equal to the scatter
+    finish to the bit. Results agree with the plain path on
+    ``EdgeShards.padded_edge_list()`` to float32 rounding, not to the bit:
+    XLA rewrites the plain program's ``z * share + segment_sum(...)`` into
+    a scatter-add onto ``z * share``, so each receiver's sum starts from
+    its kept share there, while in the sharded program the halo ``psum``
+    between the sum and the add rules that rewrite out (any receiver, not
+    only those split by a cut, can differ in its last bits). When
+    ``S * e_shard`` exceeds E the padded mask draw re-indexes edge slots,
+    so compare against the padded list, not the original (threefry bits
+    have no prefix property).
 
     ``policy`` selects the precision policy
     (:mod:`repro.core.precision`; name, :class:`Policy`, or ``None`` for
@@ -682,109 +700,75 @@ def run_pushsum_sweep(
     execution knobs arrive via ``plan=`` (loose kwargs are deprecated
     shims; see :mod:`repro.core.plan`).
     """
-    plan = resolve_plan(
-        plan, _entry="run_pushsum_sweep",
-        _supports=("backend", "mesh", "data_axis", "graph_axis",
-                   "graph_shards", "policy", "dst_sorted", "halo",
-                   "faults", "async_"),
-        **legacy)
-    backend, mesh, data_axis = plan.backend, plan.mesh, plan.data_axis
-    graph_axis, graph_shards = plan.graph_axis, plan.graph_shards
-    policy, dst_sorted, halo = plan.policy, plan.dst_sorted, plan.halo
-    faults = plan.faults
-    async_ = plan.async_
-    if isinstance(async_, AsyncModel) and is_degenerate_async(async_):
-        async_ = None
-    w = jnp.asarray(w)
-    pol = None if policy is None else resolve_policy(policy)
-    if async_ is not None and (graph_shards is not None
-                               or isinstance(el, EdgeShards)):
-        raise ValueError(
-            "async_ is incompatible with the edge-partitioned mode "
-            "(graph_shards): the per-edge stale buffer is not partitioned"
-        )
-    if graph_shards is not None or isinstance(el, EdgeShards):
-        shards = (el if isinstance(el, EdgeShards)
-                  else partition_edge_list(el, graph_shards))
-        if graph_shards is not None and shards.n_shards != graph_shards:
-            raise ValueError(
-                f"EdgeShards has {shards.n_shards} shards, "
-                f"graph_shards={graph_shards}"
-            )
-        S = shards.n_shards
-        src = shards.src if shards.is_batched else shards.src[None]
-        dst = shards.dst if shards.is_batched else shards.dst[None]
-        valid = shards.valid if shards.is_batched else shards.valid[None]
-        G = src.shape[0]                     # (G, S, Es)
-        gi, dp, sd = _scenario_grid(G, drop_probs, seeds)
-        (gi, dp, sd), fi, fstack = _expand_fault_axis((gi, dp, sd), faults)
-        K = gi.shape[0]
-        if mesh is not None:
-            if int(mesh.shape[graph_axis]) != S:
+    # host spans: the argument preparation (the partition, the scenario
+    # grid, the upload of the edge arrays), then the dispatch of the
+    # compiled scan (which returns before the device finishes)
+    with TraceAnnotation("repro.pushsum.prepare"):
+        plan = resolve_plan(
+            plan, _entry="run_pushsum_sweep",
+            _supports=("backend", "mesh", "data_axis", "graph_axis",
+                       "graph_shards", "policy", "dst_sorted", "halo",
+                       "faults", "async_"),
+            **legacy)
+        async_ = plan.async_
+        if isinstance(async_, AsyncModel) and is_degenerate_async(async_):
+            async_ = None
+        w = jnp.asarray(w)
+        pol = None if plan.policy is None else resolve_policy(plan.policy)
+        if plan.graph_shards is not None or isinstance(el, EdgeShards):
+            if async_ is not None:
                 raise ValueError(
-                    f"mesh {graph_axis} axis has {mesh.shape[graph_axis]} "
-                    f"devices but the edge list is cut into {S} shards"
+                    "async_ is incompatible with the edge-partitioned mode "
+                    "(graph_shards): the per-edge stale buffer is not "
+                    "partitioned"
                 )
-            pad = (-K) % int(mesh.shape[data_axis])
-            if pad:
-                fill = np.full(pad, K - 1)
-                gi = np.concatenate([gi, gi[fill]])
-                dp = np.concatenate([dp, dp[fill]])
-                sd = np.concatenate([sd, sd[fill]])
-                if fi is not None:
-                    fi = np.concatenate([fi, fi[fill]])
-        drop_b = jnp.asarray(dp)
-        seed_b = jnp.asarray(sd)
-        args = (w, jnp.asarray(src[gi]), jnp.asarray(dst[gi]),
-                jnp.asarray(valid[gi]), drop_b, seed_b)
-        if fi is not None:
-            args += (jax.tree_util.tree_map(
-                lambda x: x[jnp.asarray(fi)], fstack),)
-        if mesh is None:
-            errs, finals, gaps = _sweep2d_compiled(
-                *args, T=T, B=B, backend=backend,
-                graph_axis=graph_axis, n_shards=S,
-                policy=pol, halo=halo,
-            )
+            fn, args, coords = _prepare_edge_sharded(
+                w, el, T, drop_probs, seeds, B, plan, pol)
         else:
-            errs, finals, gaps = _sweep_sharded_2d(
-                mesh, data_axis, graph_axis, T, B, backend, pol, halo,
-                fi is not None,
-            )(*args)
-        return PushSumSweepResult(
-            err=errs[:K], final_ratio=finals[:K], mass_gap=gaps[:K],
-            drop_prob=drop_b[:K], seed=seed_b[:K], graph=jnp.asarray(gi[:K]),
-            fault=None if fi is None else jnp.asarray(fi[:K]),
-        )
+            fn, args, coords = _prepare_plain(
+                w, el, T, drop_probs, seeds, B, plan, pol, async_)
+    with TraceAnnotation("repro.pushsum.dispatch"):
+        errs, finals, gaps = fn(*args)
+    K, gi, drop_b, seed_b, fi, ai = coords
+    return PushSumSweepResult(
+        err=errs[:K], final_ratio=finals[:K], mass_gap=gaps[:K],
+        drop_prob=drop_b[:K], seed=seed_b[:K], graph=jnp.asarray(gi[:K]),
+        fault=None if fi is None else jnp.asarray(fi[:K]),
+        async_=None if ai is None else jnp.asarray(ai[:K]),
+    )
 
+
+def _pad_scenarios(mesh, data_axis, coords):
+    """Repeat the last scenario until the mesh's data axis divides K;
+    ``coords`` are (K,) arrays or None."""
+    K = coords[0].shape[0]
+    pad = 0 if mesh is None else (-K) % int(mesh.shape[data_axis])
+    if not pad:
+        return coords
+    fill = np.full(pad, K - 1)
+    return tuple(None if c is None else np.concatenate([c, c[fill]])
+                 for c in coords)
+
+
+def _prepare_plain(w, el, T, drop_probs, seeds, B, plan, pol, async_):
+    """The one-device (or scenario-sharded) program of
+    :func:`run_pushsum_sweep`: ``(compiled, its arguments, (K, graph,
+    drop, seed, fault, async) coordinates)``."""
     src = np.atleast_2d(el.src)      # (G, E)
     dst = np.atleast_2d(el.dst)
     valid = np.atleast_2d(el.valid)
     G, E = src.shape
     gi, dp, sd = _scenario_grid(G, drop_probs, seeds)
-    (gi, dp, sd), fi, fstack = _expand_fault_axis((gi, dp, sd), faults)
+    (gi, dp, sd), fi, fstack = _expand_fault_axis((gi, dp, sd), plan.faults)
     if fi is None:
         (gi, dp, sd), ai, astack = _expand_async_axis((gi, dp, sd), async_)
     else:
         (gi, dp, sd, fi), ai, astack = _expand_async_axis(
             (gi, dp, sd, fi), async_)
     K = gi.shape[0]
-
-    if mesh is None:
-        pad = 0
-    else:
-        n_dev = int(mesh.shape[data_axis])
-        pad = (-K) % n_dev
-        if pad:                       # repeat the last scenario to fill
-            fill = np.full(pad, K - 1)
-            gi = np.concatenate([gi, gi[fill]])
-            dp = np.concatenate([dp, dp[fill]])
-            sd = np.concatenate([sd, sd[fill]])
-            if fi is not None:
-                fi = np.concatenate([fi, fi[fill]])
-            if ai is not None:
-                ai = np.concatenate([ai, ai[fill]])
-
+    mesh = plan.mesh
+    gi, dp, sd, fi, ai = _pad_scenarios(mesh, plan.data_axis,
+                                        (gi, dp, sd, fi, ai))
     drop_b = jnp.asarray(dp)
     seed_b = jnp.asarray(sd)
     args = (w, jnp.asarray(src[gi]), jnp.asarray(dst[gi]),
@@ -796,23 +780,65 @@ def run_pushsum_sweep(
         args += (jax.tree_util.tree_map(
             lambda x: x[jnp.asarray(ai)], astack),)
     if mesh is None:
-        errs, finals, gaps = _sweep_compiled(
-            *args, T=T, B=B, backend=backend,
-            policy=pol, dst_sorted=dst_sorted,
-        )
+        fn = functools.partial(
+            _sweep_compiled, T=T, B=B, backend=plan.backend, policy=pol,
+            dst_sorted=plan.dst_sorted)
     else:
-        shard_args = args if fi is not None or ai is None else (
-            args[:6] + args[7:])     # drop the None fault_b placeholder
-        errs, finals, gaps = _sweep_sharded(
-            mesh, data_axis, T, B, backend, pol, dst_sorted,
-            fi is not None, ai is not None,
-        )(*shard_args)
-    return PushSumSweepResult(
-        err=errs[:K], final_ratio=finals[:K], mass_gap=gaps[:K],
-        drop_prob=drop_b[:K], seed=seed_b[:K], graph=jnp.asarray(gi[:K]),
-        fault=None if fi is None else jnp.asarray(fi[:K]),
-        async_=None if ai is None else jnp.asarray(ai[:K]),
-    )
+        if fi is None and ai is not None:
+            args = args[:6] + args[7:]   # drop the None fault_b placeholder
+        fn = _sweep_sharded(mesh, plan.data_axis, T, B, plan.backend, pol,
+                            plan.dst_sorted, fi is not None, ai is not None)
+    return fn, args, (K, gi, drop_b, seed_b, fi, ai)
+
+
+def _prepare_edge_sharded(w, el, T, drop_probs, seeds, B, plan, pol):
+    """The edge-partitioned program of :func:`run_pushsum_sweep`, as
+    :func:`_prepare_plain`. With a mesh the edge arrays go from the host
+    straight to the devices that hold them."""
+    graph_shards, graph_axis, mesh = (plan.graph_shards, plan.graph_axis,
+                                      plan.mesh)
+    shards = (el if isinstance(el, EdgeShards)
+              else partition_edge_list(el, graph_shards))
+    if graph_shards is not None and shards.n_shards != graph_shards:
+        raise ValueError(
+            f"EdgeShards has {shards.n_shards} shards, "
+            f"graph_shards={graph_shards}"
+        )
+    S = shards.n_shards
+    lo, ends = shards.run_end_windows()
+    edge_arrays = (shards.src, shards.dst, shards.valid, lo, ends)
+    if not shards.is_batched:
+        edge_arrays = tuple(a[None] for a in edge_arrays)   # (G, S, ...)
+    gi, dp, sd = _scenario_grid(edge_arrays[0].shape[0], drop_probs, seeds)
+    (gi, dp, sd), fi, fstack = _expand_fault_axis((gi, dp, sd), plan.faults)
+    K = gi.shape[0]
+    put = jnp.asarray
+    if mesh is not None:
+        if int(mesh.shape[graph_axis]) != S:
+            raise ValueError(
+                f"mesh {graph_axis} axis has {mesh.shape[graph_axis]} "
+                f"devices but the edge list is cut into {S} shards"
+            )
+        from repro.distributed.sharding import sweep_specs
+
+        layout = NamedSharding(
+            mesh, sweep_specs(plan.data_axis, graph_axis)["edge_shards"])
+        put = functools.partial(jax.device_put, device=layout)
+    gi, dp, sd, fi = _pad_scenarios(mesh, plan.data_axis, (gi, dp, sd, fi))
+    drop_b = jnp.asarray(dp)
+    seed_b = jnp.asarray(sd)
+    args = (w, *(put(a[gi]) for a in edge_arrays), drop_b, seed_b)
+    if fi is not None:
+        args += (jax.tree_util.tree_map(
+            lambda x: x[jnp.asarray(fi)], fstack),)
+    if mesh is None:
+        fn = functools.partial(
+            _sweep2d_compiled, T=T, B=B, backend=plan.backend,
+            graph_axis=graph_axis, n_shards=S, policy=pol, halo=plan.halo)
+    else:
+        fn = _sweep_sharded_2d(mesh, plan.data_axis, graph_axis, T, B,
+                               plan.backend, pol, plan.halo, fi is not None)
+    return fn, args, (K, gi, drop_b, seed_b, fi, None)
 
 
 # Compiled Algorithm-2 sweeps, one jitted vmapped scan per

@@ -34,6 +34,7 @@ def edge_scatter(
     backend: str = "auto",
     *,
     seg_end: jnp.ndarray | None = None,
+    seg_lo: jnp.ndarray | None = None,
     block_e: int = 4096,
     interpret: bool | None = None,
     indices_sorted: bool = False,
@@ -50,14 +51,15 @@ def edge_scatter(
     ``seg_end`` (each receiver's last in-edge of a dst-sorted index, ``-1``
     for none) lets the Pallas lowering finish with a gather at the run
     ends instead of the ``segment_sum`` scatter; the XLA lowering, the
-    oracle, does not read it.
+    oracle, does not read it. With ``seg_lo`` it covers only the receivers
+    from ``seg_lo`` on (see :func:`.pushsum_edge.edge_scatter_pallas`).
     """
     if resolve_backend(backend) == "xla":
         return edge_scatter_ref(sigma, rho, live, src, dst,
                                 indices_sorted=indices_sorted,
                                 accum_dtype=accum_dtype)
     return edge_scatter_pallas(
-        sigma, rho, live, src, dst, seg_end=seg_end, block_e=block_e,
-        interpret=interpret, accum_dtype=accum_dtype,
+        sigma, rho, live, src, dst, seg_end=seg_end, seg_lo=seg_lo,
+        block_e=block_e, interpret=interpret, accum_dtype=accum_dtype,
         indices_sorted=indices_sorted,
     )

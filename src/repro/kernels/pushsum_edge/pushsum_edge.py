@@ -137,6 +137,7 @@ def edge_scatter_pallas(
     dst: jnp.ndarray,     # (E,) int32, pre-sorted ascending for the fast path
     *,
     seg_end: jnp.ndarray | None = None,  # (N,) int32 last in-edge, or -1
+    seg_lo: jnp.ndarray | None = None,   # () int32 first receiver of seg_end
     block_e: int = 4096,
     interpret: bool | None = None,
     accum_dtype: str | None = None,
@@ -158,7 +159,11 @@ def edge_scatter_pallas(
     run open at each block's end into the next block, so the last lane of
     each receiver's whole run holds its full increment sum, and the finish
     gathers those N lanes instead of scattering all E partials.
-    ``seg_end=None`` is the scatter finish.
+    ``seg_end=None`` is the scatter finish. With ``seg_lo``, ``seg_end``
+    holds the run ends of the ``W`` receivers ``seg_lo .. seg_lo + W - 1``
+    only (``seg_lo + W <= N``): the finish gathers ``W`` lanes and every
+    other receiver's sum is zero, as for an edge shard whose receivers lie
+    in one range.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -208,4 +213,7 @@ def edge_scatter_pallas(
         with jax.named_scope("run_end_gather"):
             recv = jnp.where((seg_end >= 0)[:, None],
                              upd_t[:, jnp.maximum(seg_end, 0)].T, 0.0)
+            if seg_lo is not None:
+                recv = jax.lax.dynamic_update_slice(
+                    jnp.zeros((n, D), recv.dtype), recv, (seg_lo, 0))
     return rho_new_t[:, :E].T, recv

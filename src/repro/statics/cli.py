@@ -296,6 +296,9 @@ def _pushsum_sharded_fixture():
     src_k = np.broadcast_to(sh.src[None], (2,) + sh.src.shape).copy()
     dst_k = np.broadcast_to(sh.dst[None], (2,) + sh.dst.shape).copy()
     val_k = np.broadcast_to(sh.valid[None], (2,) + sh.valid.shape).copy()
+    lo, ends = sh.run_end_windows()
+    lo_k = np.broadcast_to(lo[None], (2,) + lo.shape).copy()
+    ends_k = np.broadcast_to(ends[None], (2,) + ends.shape).copy()
     drop_b = np.array([0.1, 0.3], np.float32)
     seed_b = np.array([0, 1], np.uint32)
     dims = {"N": 11, "d": 3, "T": 5, "S": sh.n_shards,
@@ -304,12 +307,12 @@ def _pushsum_sharded_fixture():
 
     def make(backend, store):
         return walk.trace(
-            lambda w_, s_, d_, v_, dp_, sd_: _sweep2d_emulated(
-                w_, s_, d_, v_, dp_, sd_,
+            lambda w_, s_, d_, v_, lo_, e_, dp_, sd_: _sweep2d_emulated(
+                w_, s_, d_, v_, lo_, e_, dp_, sd_,
                 T=5, B=2, backend=backend,
                 graph_axis="shardlint", n_shards=sh.n_shards,
             ),
-            w, src_k, dst_k, val_k, drop_b, seed_b,
+            w, src_k, dst_k, val_k, lo_k, ends_k, drop_b, seed_b,
         )
 
     return dims, (None,), make

@@ -24,7 +24,7 @@ from repro.kernels.social_innov.social_innov import innovation_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import compilation_cache, topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -38,8 +38,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_text(fn, sharding, *shapes):
@@ -94,3 +99,51 @@ def test_social_innov_compiles_at_N131072(one_chip):
         ((n,), jnp.float32), ((n, s), jnp.float32),
         ((n, m, s), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+def test_pushsum_edge_shard_window_compiles_at_chord_shard(one_chip):
+    """One chip's shard of the Chord cell: N=2^24 receivers, E/4 =
+    100,663,296 edges, d+1=4, finishing with a gather over its own window of
+    2^22 run ends placed from ``seg_lo``."""
+    n, e, d, w = 1 << 24, 24 << 22, 4, 1 << 22
+    text = _compiled_text(
+        lambda *a: edge_scatter_pallas(*a[:5], seg_end=a[5], seg_lo=a[6],
+                                       interpret=False),
+        one_chip, ((n, d), jnp.float32), ((e, d), jnp.float32),
+        ((e,), jnp.bool_), ((e,), jnp.int32), ((e,), jnp.int32),
+        ((w,), jnp.int32), ((), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert "run_end_gather" in text and "scatter" not in text.replace(
+        "edge_scatter_pallas", "")
+
+
+def test_edge_sharded_sweep_compiles_for_four_chips(topo, monkeypatch):
+    """The whole edge-partitioned sweep of the Chord cell on the described
+    2x2 mesh: the Pallas step on each chip's 100,663,296 edges, the halo
+    all-reduce, and each chip's arguments and temporaries within its
+    16 GB."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core import sweeps
+    from repro.distributed.sharding import sweep_mesh
+
+    # the program picks its kernels by the default backend: a TPU's here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = sweep_mesh(1, 4, devices=list(topo.devices))
+    n, d, es, w = 1 << 24, 3, 24 << 22, 1 << 22
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    edges = P("data", "graph")
+    compiled = sweeps._sweep_sharded_2d(
+        mesh, "data", "graph", 4, 4, "auto").lower(
+        arg((n, d), jnp.float32, P()), arg((1, 4, es), jnp.int32, edges),
+        arg((1, 4, es), jnp.int32, edges), arg((1, 4, es), jnp.bool_, edges),
+        arg((1, 4), jnp.int32, edges), arg((1, 4, w), jnp.int32, edges),
+        arg((1,), jnp.float32, P("data")),
+        arg((1,), jnp.uint32, P("data"))).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    assert "run_end_gather" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
