@@ -621,6 +621,15 @@ class EdgeShards:
     Fields carry a leading graph axis (G, S, E_shard) when built from a
     batched :class:`EdgeList`, else (S, E_shard); ``boundary`` is
     correspondingly (G, N) or (N,), ``run_ends`` (G, S, N) or (S, N).
+
+    An instance and the arrays it views (the source :class:`EdgeList`'s
+    arrays too, where a shard is a view of them) must not change after
+    partition: :func:`partition_edge_list` returns its arrays read-only,
+    and an instance keeps what it derives from them while it lives — its
+    :meth:`run_end_windows` and the device placement of its edge arrays
+    (:func:`repro.core.sweeps.run_pushsum_sweep`). A graph that differs is
+    a new instance (``dataclasses.replace``), which starts with none of
+    that.
     """
 
     src: np.ndarray       # (..., S, E_shard) int32
@@ -630,6 +639,10 @@ class EdgeShards:
     e_total: int          # edge count of the (padded) source EdgeList
     boundary: np.ndarray  # (..., N) bool halo index — receivers split by cuts
     run_ends: np.ndarray  # (..., S, N) int32 per-shard run ends, or -1
+    # what is derived from the arrays above, kept per instance: a replace()
+    # starts empty, and eq, hash and repr leave it out
+    _memo: dict = dataclasses.field(default_factory=dict, init=False,
+                                    repr=False, compare=False)
 
     @property
     def n_shards(self) -> int:
@@ -675,16 +688,21 @@ class EdgeShards:
         range a shard holds): ``ends[..., k, i]`` is ``run_ends[..., k,
         lo[..., k] + i]``. A shard's receivers are contiguous, so its window
         holds every run that ends on it; ``lo`` (int32, (..., S)) is kept
-        at most ``N - W``."""
-        held = self.run_ends >= 0
-        idx = np.arange(self.n)
-        first = np.where(held, idx, self.n).min(axis=-1)
-        last = np.where(held, idx, -1).max(axis=-1)
-        width = max(int((last - first + 1).max(initial=1)), 1)
-        lo = np.minimum(np.where(last >= 0, first, 0), self.n - width)
-        ends = np.take_along_axis(
-            self.run_ends, lo[..., None] + np.arange(width), axis=-1)
-        return lo.astype(np.int32), ends
+        at most ``N - W``. Computed once an instance, read-only."""
+        if "windows" not in self._memo:
+            held = self.run_ends >= 0
+            idx = np.arange(self.n)
+            first = np.where(held, idx, self.n).min(axis=-1)
+            last = np.where(held, idx, -1).max(axis=-1)
+            width = max(int((last - first + 1).max(initial=1)), 1)
+            lo = np.minimum(np.where(last >= 0, first, 0), self.n - width)
+            ends = np.take_along_axis(
+                self.run_ends, lo[..., None] + np.arange(width), axis=-1)
+            windows = (lo.astype(np.int32), ends)
+            for a in windows:
+                a.setflags(write=False)
+            self._memo["windows"] = windows
+        return self._memo["windows"]
 
 
 def _shard_run_ends(dst_s: np.ndarray, real: np.ndarray,
@@ -712,7 +730,8 @@ def partition_edge_list(el: EdgeList, n_shards: int) -> EdgeShards:
     then one reshape of the padded index (a view where no padding is
     needed). Batched edge lists partition every topology draw
     independently under one shared capacity, so a whole scenario grid
-    rides a single (G, S, E_shard) layout.
+    rides a single (G, S, E_shard) layout. The arrays returned are
+    read-only.
     """
     if n_shards < 1:
         raise ValueError(f"n_shards must be >= 1, got {n_shards}")
@@ -748,6 +767,8 @@ def partition_edge_list(el: EdgeList, n_shards: int) -> EdgeShards:
         src_s, dst_s, val_s, bnd, ends = (np.stack(x) for x in zip(*parts))
     else:
         src_s, dst_s, val_s, bnd, ends = one(el.src, el.dst, el.valid)
+    for a in (src_s, dst_s, val_s, bnd, ends):
+        a.setflags(write=False)         # see EdgeShards: fixed once cut
     return EdgeShards(src=src_s, dst=dst_s, valid=val_s, n=el.n,
                       e_total=el.E, boundary=bnd, run_ends=ends)
 

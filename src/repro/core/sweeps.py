@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from collections import OrderedDict
 from typing import NamedTuple, Sequence
 
@@ -127,6 +128,7 @@ __all__ = [
     "CacheHandle",
     "CacheInfo",
     "cache_registry",
+    "edge_placement_info",
     "run_pushsum_sweep",
     "run_byzantine_sweep",
     "run_byzantine_grid",
@@ -667,7 +669,11 @@ def run_pushsum_sweep(
     only those split by a cut, can differ in its last bits). When
     ``S * e_shard`` exceeds E the padded mask draw re-indexes edge slots,
     so compare against the padded list, not the original (threefry bits
-    have no prefix property).
+    have no prefix property). Passing an :class:`graphs.EdgeShards` built
+    once keeps the graph on the devices across calls: the edge arrays are
+    uploaded in the first call and reused while the instance lives (for
+    the same mesh and scenario graphs); an :class:`graphs.EdgeList` is
+    partitioned and uploaded anew in every call.
 
     ``policy`` selects the precision policy
     (:mod:`repro.core.precision`; name, :class:`Policy`, or ``None`` for
@@ -701,8 +707,9 @@ def run_pushsum_sweep(
     shims; see :mod:`repro.core.plan`).
     """
     # host spans: the argument preparation (the partition, the scenario
-    # grid, the upload of the edge arrays), then the dispatch of the
-    # compiled scan (which returns before the device finishes)
+    # grid, the upload of the edge arrays where they are not placed yet),
+    # then the dispatch of the compiled scan (which returns before the
+    # device finishes)
     with TraceAnnotation("repro.pushsum.prepare"):
         plan = resolve_plan(
             plan, _entry="run_pushsum_sweep",
@@ -791,10 +798,66 @@ def _prepare_plain(w, el, T, drop_probs, seeds, B, plan, pol, async_):
     return fn, args, (K, gi, drop_b, seed_b, fi, ai)
 
 
+# The device placement of EdgeShards graphs (_place_edge_shards): its hit
+# and miss counts, and the live instances that hold a placement, by id and
+# held weakly, so that a graph dropped frees its device arrays.
+_EDGE_PLACEMENT = {"hits": 0, "misses": 0}
+_PLACED: dict[int, weakref.ref] = {}
+
+
+def _place_edge_shards(shards: EdgeShards, gi: np.ndarray, layout):
+    """The edge arguments ``(src, dst, valid, lo, ends)`` of the scenarios'
+    graphs ``gi``, on the devices of ``layout`` (``None``: the default
+    device), each (K, S, ...).
+
+    The placement is kept on ``shards`` itself and reused while the
+    instance lives, for the same ``layout`` and ``gi`` (an instance does not
+    change, see :class:`graphs.EdgeShards`); one an instance, the latest,
+    so another ``layout`` or K frees the one before. The compiled programs
+    donate none of these buffers."""
+    key = (layout, gi.tobytes())
+    placed = shards._memo.get("placed")
+    if placed is not None and placed[0] == key:
+        _EDGE_PLACEMENT["hits"] += 1
+        return placed[1]
+    _EDGE_PLACEMENT["misses"] += 1
+    shards._memo.pop("placed", None)
+    lo, ends = shards.run_end_windows()
+    arrays = (shards.src, shards.dst, shards.valid, lo, ends)
+    if not shards.is_batched:
+        arrays = tuple(a[None] for a in arrays)             # (G, S, ...)
+    put = (jnp.asarray if layout is None
+           else functools.partial(jax.device_put, device=layout))
+    out = tuple(put(a[gi]) for a in arrays)
+    shards._memo["placed"] = (key, out)
+    k = id(shards)
+    if k not in _PLACED:
+        _PLACED[k] = weakref.ref(shards, lambda _, k=k: _PLACED.pop(k, None))
+    return out
+
+
+def _clear_edge_placements() -> None:
+    """Drop every live placement, and the counts."""
+    for ref in list(_PLACED.values()):
+        shards = ref()
+        if shards is not None:
+            shards._memo.pop("placed", None)
+    _PLACED.clear()
+    _EDGE_PLACEMENT.update(hits=0, misses=0)
+
+
+def edge_placement_info() -> dict[str, int]:
+    """``hits`` and ``misses`` of the device placement kept on an
+    :class:`graphs.EdgeShards` across :func:`run_pushsum_sweep` calls, and
+    ``live``, the instances that hold one now."""
+    return dict(_EDGE_PLACEMENT, live=len(_PLACED))
+
+
 def _prepare_edge_sharded(w, el, T, drop_probs, seeds, B, plan, pol):
     """The edge-partitioned program of :func:`run_pushsum_sweep`, as
     :func:`_prepare_plain`. With a mesh the edge arrays go from the host
-    straight to the devices that hold them."""
+    straight to the devices that hold them, once for an
+    :class:`graphs.EdgeShards` passed in (:func:`_place_edge_shards`)."""
     graph_shards, graph_axis, mesh = (plan.graph_shards, plan.graph_axis,
                                       plan.mesh)
     shards = (el if isinstance(el, EdgeShards)
@@ -805,14 +868,11 @@ def _prepare_edge_sharded(w, el, T, drop_probs, seeds, B, plan, pol):
             f"graph_shards={graph_shards}"
         )
     S = shards.n_shards
-    lo, ends = shards.run_end_windows()
-    edge_arrays = (shards.src, shards.dst, shards.valid, lo, ends)
-    if not shards.is_batched:
-        edge_arrays = tuple(a[None] for a in edge_arrays)   # (G, S, ...)
-    gi, dp, sd = _scenario_grid(edge_arrays[0].shape[0], drop_probs, seeds)
+    G = shards.src.shape[0] if shards.is_batched else 1
+    gi, dp, sd = _scenario_grid(G, drop_probs, seeds)
     (gi, dp, sd), fi, fstack = _expand_fault_axis((gi, dp, sd), plan.faults)
     K = gi.shape[0]
-    put = jnp.asarray
+    layout = None
     if mesh is not None:
         if int(mesh.shape[graph_axis]) != S:
             raise ValueError(
@@ -823,11 +883,10 @@ def _prepare_edge_sharded(w, el, T, drop_probs, seeds, B, plan, pol):
 
         layout = NamedSharding(
             mesh, sweep_specs(plan.data_axis, graph_axis)["edge_shards"])
-        put = functools.partial(jax.device_put, device=layout)
     gi, dp, sd, fi = _pad_scenarios(mesh, plan.data_axis, (gi, dp, sd, fi))
     drop_b = jnp.asarray(dp)
     seed_b = jnp.asarray(sd)
-    args = (w, *(put(a[gi]) for a in edge_arrays), drop_b, seed_b)
+    args = (w, *_place_edge_shards(shards, gi, layout), drop_b, seed_b)
     if fi is not None:
         args += (jax.tree_util.tree_map(
             lambda x: x[jnp.asarray(fi)], fstack),)
@@ -1853,6 +1912,8 @@ def cache_registry() -> dict[str, CacheHandle]:
         _jit("pushsum.sweep2d-jit", _sweep2d_compiled),
         _factory("pushsum.sweep-sharded", _sweep_sharded),
         _factory("pushsum.sweep2d-sharded", _sweep_sharded_2d),
+        CacheHandle("pushsum.edge-placement", lambda: len(_PLACED), None,
+                    _clear_edge_placements),
         CacheHandle(
             "pushsum.step-jit", _step_jit_entries, None, _STEP_JIT.clear
         ),

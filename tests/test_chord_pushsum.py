@@ -7,15 +7,20 @@ against the loop partitioner they replace; each shard's run ends and their
 windows; the run-end gather finish of every shard equal to the scatter
 finish to the bit, under the ``vmap(axis_name=)`` emulation and on a
 four-device CPU mesh (in a child interpreter), where the mesh path also
-equals the emulation to the bit; and the sweep's phase scopes and host
-spans.
+equals the emulation to the bit; the sweep's phase scopes and host spans;
+and the device placement an ``EdgeShards`` keeps across calls (hit, miss on
+a replaced graph or another K, freed with the instance, read-only arrays),
+under the emulation and on the four-device mesh.
 """
+import dataclasses
+import gc
 import json
 import os
 import re
 import subprocess
 import sys
 import textwrap
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -357,3 +362,204 @@ def test_mesh_upload_places_each_shard_on_its_device():
         ExecutionPlan(mesh=mesh, graph_shards=1), None)
     for a in args[1:6]:
         assert a.sharding.spec == jax.sharding.PartitionSpec("data", "graph")
+
+
+# --- the device placement an EdgeShards keeps across calls -------------------
+
+def _placement_case():
+    el = chord_edge_list(8, 4)
+    w = np.random.default_rng(4).normal(size=(el.n, 2)).astype(np.float32)
+    return el, w
+
+
+def _sweep(w, sh, plan, seeds=5):
+    r = run_pushsum_sweep(w, sh, 3, drop_probs=0.3, seeds=seeds, B=2,
+                          plan=plan)
+    return [np.asarray(x) for x in (r.err, r.final_ratio, r.mass_gap)]
+
+
+def _same(a, b):
+    return all(np.array_equal(x, y) for x, y in zip(a, b))
+
+
+def _spy_edge_uploads(monkeypatch):
+    """The (K, S, ...) integer and mask host arrays put on a device,
+    through either path."""
+    seen = []
+
+    def spy(f):
+        def put(x, *a, **k):
+            if _edge_upload(x):
+                seen.append(x.shape)
+            return f(x, *a, **k)
+        return put
+    monkeypatch.setattr(jax, "device_put", spy(jax.device_put))
+    monkeypatch.setattr(jnp, "asarray", spy(jnp.asarray))
+    return seen
+
+
+def _edge_upload(x):
+    return (isinstance(x, np.ndarray) and x.ndim >= 2
+            and x.dtype.kind in "ib")
+
+
+def _placement():
+    return sweeps.cache_registry()["pushsum.edge-placement"]
+
+
+def test_second_call_reuses_the_placement(monkeypatch):
+    el, w = _placement_case()
+    plan = ExecutionPlan(graph_shards=2)
+    _placement().clear()
+    sh = partition_edge_list(el, 2)
+    seen = _spy_edge_uploads(monkeypatch)
+    first = _sweep(w, sh, plan)
+    assert len(seen) == 5                    # src, dst, valid, lo, ends
+    assert sweeps.edge_placement_info() == {"hits": 0, "misses": 1,
+                                            "live": 1}
+    seen.clear()
+    second = _sweep(w, sh, plan)
+    assert seen == []                        # nothing uploaded again
+    assert sweeps.edge_placement_info()["hits"] == 1
+    # reused buffers (none donated) give the answer of a fresh partition
+    assert _same(first, second)
+    assert _same(first, _sweep(w, partition_edge_list(el, 2), plan))
+
+
+def test_replaced_graph_misses_and_differs():
+    el, w = _placement_case()
+    plan = ExecutionPlan(graph_shards=2)
+    sh = partition_edge_list(el, 2)
+    base = _sweep(w, sh, plan)
+    valid = sh.valid.copy()
+    valid[1] = False                         # the second shard carries nothing
+    broken = dataclasses.replace(sh, valid=valid)
+    assert "_memo" not in repr(broken)
+    assert "_memo" not in [f.name for f in dataclasses.fields(sh)
+                           if f.compare or f.hash]
+    misses = sweeps.edge_placement_info()["misses"]
+    got = _sweep(w, broken, plan)
+    assert sweeps.edge_placement_info()["misses"] == misses + 1
+    assert not np.array_equal(got[1], base[1])
+    assert _same(base, _sweep(w, sh, plan))  # the original is untouched
+
+
+def test_placement_is_freed_with_its_graph():
+    el, w = _placement_case()
+    plan = ExecutionPlan(graph_shards=2)
+    _placement().clear()
+    sh = partition_edge_list(el, 2)
+    _sweep(w, sh, plan)
+    _, args, _ = sweeps._prepare_edge_sharded(
+        jnp.asarray(w), sh, 3, 0.3, 5, 2, plan, None)
+    ref = weakref.ref(args[1])               # the placed src
+    assert _placement().cache_info().currsize == 1
+    del args, sh
+    gc.collect()
+    assert ref() is None
+    assert _placement().cache_info().currsize == 0
+
+
+@pytest.mark.parametrize("name", ["chord", "unsorted"])
+def test_partition_arrays_are_read_only(name):
+    sh = partition_edge_list(_graphs()[name], 3)
+    for a in (sh.src, sh.dst, sh.valid, sh.boundary, sh.run_ends,
+              *sh.run_end_windows()):
+        with pytest.raises(ValueError, match="read-only"):
+            a[(0,) * a.ndim] = a[(0,) * a.ndim]
+    assert sh.run_end_windows()[1] is sh.run_end_windows()[1]
+
+
+def test_another_k_replaces_the_one_placement():
+    el, w = _placement_case()
+    plan = ExecutionPlan(graph_shards=2)
+    h = _placement()
+    h.clear()
+    sh = partition_edge_list(el, 2)
+    one = _sweep(w, sh, plan)
+    two = _sweep(w, sh, plan, seeds=[5, 6])     # K = 2: a miss
+    assert _same([a[:1] for a in two], one)
+    _sweep(w, sh, plan, seeds=[5, 6])            # a hit
+    _sweep(w, sh, plan)                          # K = 1 again: a miss
+    assert sweeps.edge_placement_info() == {"hits": 1, "misses": 3,
+                                            "live": 1}
+    assert h.cache_info().currsize == 1
+    # an EdgeList is partitioned in each call and never hits
+    _sweep(w, el, plan)
+    _sweep(w, el, plan)
+    assert sweeps.edge_placement_info() == {"hits": 1, "misses": 5,
+                                            "live": 1}
+    h.clear()
+    assert h.cache_info().currsize == 0
+    assert sweeps.edge_placement_info() == {"hits": 0, "misses": 0,
+                                            "live": 0}
+    assert _same(one, _sweep(w, sh, plan))
+    assert sweeps.edge_placement_info()["misses"] == 1
+
+
+_PLACEMENT_MESH_SCRIPT = textwrap.dedent("""
+    import dataclasses, gc, json, sys, weakref
+    sys.path.insert(0, {src!r}); sys.path.insert(0, {tests!r})
+    import jax, numpy as np
+    from repro.core import sweeps
+    from repro.core.graphs import partition_edge_list
+    from repro.core.plan import ExecutionPlan
+    from repro.distributed.sharding import sweep_mesh
+    from test_chord_pushsum import (_edge_upload, _placement_case, _same,
+                                    _sweep)
+    el, w = _placement_case()
+    plan = ExecutionPlan(mesh=sweep_mesh(1, 4), graph_shards=4)
+    sh = partition_edge_list(el, 4)
+    puts = []
+    put = jax.device_put
+    def spy(x, *a, **k):
+        if _edge_upload(x):
+            puts.append(x.shape)
+        return put(x, *a, **k)
+    jax.device_put = spy
+    first = _sweep(w, sh, plan)
+    n_first = len(puts)
+    second = _sweep(w, sh, plan)
+    jax.device_put = put
+    valid = sh.valid.copy()
+    valid[1] = False
+    broken = _sweep(w, dataclasses.replace(sh, valid=valid), plan)
+    other_k = _sweep(w, sh, plan, seeds=[5, 6])
+    info = sweeps.edge_placement_info()
+    _, args, _ = sweeps._prepare_edge_sharded(
+        w, sh, 3, 0.3, [5, 6], 2, plan, None)
+    ref = weakref.ref(args[1])
+    devices = len(args[1].sharding.device_set)
+    del args, sh
+    gc.collect()
+    print(json.dumps({{
+        "uploads": [n_first, len(puts) - n_first],
+        "second_is_first": _same(first, second),
+        "fresh_is_first": _same(first, _sweep(
+            w, partition_edge_list(el, 4), plan)),
+        "replaced_differs": not np.array_equal(broken[1], first[1]),
+        "other_k_row_is_first": _same([a[:1] for a in other_k], first),
+        "info": info,
+        "devices": devices,
+        "freed": ref() is None,
+        "live_after": sweeps.edge_placement_info()["live"],
+    }}))
+""")
+
+
+@pytest.mark.multidevice
+def test_placement_on_a_four_device_mesh():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    script = _PLACEMENT_MESH_SCRIPT.format(
+        src=os.path.join(REPO, "src"), tests=os.path.join(REPO, "tests"))
+    proc = subprocess.run([sys.executable, "-c", script], env=env, cwd=REPO,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out == {
+        "uploads": [5, 0], "second_is_first": True, "fresh_is_first": True,
+        "replaced_differs": True, "other_k_row_is_first": True,
+        # calls: first (miss), second (hit), replaced (miss), K = 2 (miss)
+        "info": {"hits": 1, "misses": 3, "live": 1},
+        "devices": 4, "freed": True, "live_after": 0}, out
